@@ -28,7 +28,6 @@ the whole module at toy scale (~30 s budget, used by the CI scenarios job).
 """
 from __future__ import annotations
 
-import json
 import sys
 import time
 from typing import Callable, Dict
@@ -426,20 +425,6 @@ def _serial_point(cfg: dict, point: SweepPoint) -> float:
     return sim.run_scenario(sc).steady_state.agg_throughput
 
 
-def serial_sweep_point_main(argv) -> int:
-    """``--sweep-point`` entry: run ONE sweep point in THIS process — the
-    pre-fleet sweep shape (one machine/one configuration per Python
-    process), so each machine pays interpreter start, jax import and
-    trace+compile. ``sweep_bench`` times these subprocesses end to end as
-    the ``serial_per_process`` reference."""
-    spec = json.loads(argv[argv.index("--sweep-point") + 1])
-    cfg = _sweep_config(spec["smoke"])
-    point = sweep_points(cfg["n_machines"], cfg["budget"])[spec["index"]]
-    tput = _serial_point(cfg, point)
-    print(f"SWEEP_POINT_RESULT {point.name} {tput:.6g}")
-    return 0
-
-
 def sweep_fleet_smoke() -> dict:
     """Fleet-only smoke sweep for the CI perf gate: the gate checks that
     every machine completes AND that the sharded/pipelined overlap metadata
@@ -484,8 +469,9 @@ def sweep_bench(smoke: bool = False) -> dict:
       * ``serial``  — the strongest serial baseline: all machines looped
         in ONE warm process (shared jit cache), exact per-epoch driving;
       * ``serial_per_process`` — the pre-fleet sweep harness shape the
-        fleet replaces: one machine/one configuration per Python process
-        (fresh interpreter, jax import, trace+compile per machine).
+        fleet replaces: one machine/one configuration at a time, paying
+        trace+compile per machine (JAX's caches cleared before each point;
+        in-process, so interpreter start and jax import are not counted).
 
     Headline claims, each against its own fixed reference so nothing is
     conflated: >= 4x aggregate machine-epochs/sec is fleet vs
@@ -579,25 +565,16 @@ def sweep_bench(smoke: bool = False) -> dict:
     serial_wall = time.time() - t0
 
     import os
-    import subprocess
 
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(repo_root, "src") + os.pathsep + repo_root
+    # the pre-fleet shape paid trace+compile per machine: clear JAX's
+    # in-memory caches before each point. It runs in this process — a child
+    # process would need the device this process already holds.
     per_process_steady = {}
     t0 = time.time()
-    for i, p in enumerate(points):
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.dynamic_workload",
-             "--sweep-point", json.dumps({"smoke": smoke, "index": i})],
-            cwd=repo_root, env=env, capture_output=True, text=True, check=True,
-        )
-        for line in out.stdout.splitlines():
-            if line.startswith("SWEEP_POINT_RESULT"):
-                _tag, name, tput = line.split()
-                per_process_steady[name] = float(tput)
+    for p in points:
+        jax.clear_caches()
+        per_process_steady[p.name] = _serial_point(cfg, p)
     per_process_wall = time.time() - t0
-    assert set(per_process_steady) == {p.name for p in points}
 
     me = n_machines * n_epochs
     fleet_eps = me / fleet_res.wall_s
@@ -635,9 +612,10 @@ def sweep_bench(smoke: bool = False) -> dict:
             "wall_s": round(per_process_wall, 3),
             "machine_epochs": me,
             "agg_epochs_per_sec": round(me / per_process_wall, 2),
-            "driver": "one machine/one configuration per Python process "
-                      "(the pre-fleet sweep shape: fresh interpreter, jax "
-                      "import, trace+compile per machine)",
+            "driver": "one machine/one configuration at a time with JAX's "
+                      "compile caches cleared before each (the pre-fleet "
+                      "sweep shape: trace+compile per machine; in-process, "
+                      "so interpreter start and jax import are not counted)",
         },
         "fleet_single_device": {
             "wall_s": round(single_res.wall_s, 3),
@@ -848,8 +826,6 @@ def _print_faults(fl: dict) -> int:
 
 def main(argv) -> int:
     smoke = "--smoke" in argv
-    if "--sweep-point" in argv:
-        return serial_sweep_point_main(argv)
     if "--faults" in argv:
         return _print_faults(faults_bench(smoke=smoke))
     if "--sweep" in argv:

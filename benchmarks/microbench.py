@@ -16,10 +16,7 @@ from benchmarks.common import Rows, platform_metadata
 from repro.core import policy
 from repro.core.manager import CentralManager
 from repro.core.types import PageState, PolicyParams, TenantState, TIER_FAST, TIER_SLOW
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.hot_bins import hot_bins
-from repro.kernels.page_copy import page_move
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import flash_attention, hot_bins, page_move, paged_attention
 
 # Seed-commit (c35e7fc, lexsort ranks + W=4096 window) measurement of
 # micro_policy_epoch_64k_pages on the reference CI host — the fixed baseline
@@ -466,7 +463,7 @@ def run() -> Rows:
     rows.add("micro_hot_bins_4k_pages_2k_samples", us, "tile=512")
 
     # page_copy kernel: 64 x 0.5 MB pages
-    pool = jnp.asarray(rng.normal(size=(256, 131072)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(256, 1024, 128)), jnp.float32)
     sid = jnp.asarray(rng.choice(256, 64, replace=False), jnp.int32)
     did = jnp.asarray(rng.choice(256, 64, replace=False), jnp.int32)
     us = _time(lambda: page_move(jnp.copy(pool), sid, did), n=5)

@@ -108,6 +108,9 @@ def write_autotune_json(path: str = "BENCH_autotune.json", smoke: bool = False) 
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         dynamic_workload,
         engine_qos,

@@ -7,13 +7,13 @@ plans (the convention ``kernels/page_copy.py`` documents). A host-side frame
 table maps page id -> frame; the control plane (allocate/free) is host
 bookkeeping, while every data movement goes through the Pallas kernels:
 
-  * migrations  — ONE :func:`repro.kernels.page_copy.page_move` call per
+  * migrations  — ONE :func:`repro.kernels.ops.page_move` call per
     drained batch: demote entries first (their vacated fast frames are
-    legally reused as promote destinations — the grid reads a row before
-    any later step writes it), then promotes, padded to a fixed plan size
-    with trash-row self-copies so plan shapes never retrace;
+    legally reused as promote destinations — the kernel gathers every
+    source row before it writes any), then promotes, padded to a fixed
+    plan size with trash-row self-copies so plan shapes never retrace;
   * bulk writes — tenant data is staged host-side and DMA'd into frames
-    with :func:`repro.kernels.page_copy.page_copy` (staging pool -> page
+    with :func:`repro.kernels.ops.page_copy` (staging pool -> page
     pool), again trash-padded to the fixed plan size.
 
 ``CentralManager(data_plane_elems=...)`` owns a pool and feeds it the
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import TIER_FAST
-from repro.kernels.page_copy import page_copy, page_move
+from repro.kernels import ops
 
 
 class PagePool:
@@ -40,13 +40,11 @@ class PagePool:
         row_elems: int = 128,
         dtype=jnp.float32,
         plan_slots: int = 64,
-        interpret: bool = True,
     ):
         self.num_pages = num_pages
         self.fast_capacity = fast_capacity
         self.row_elems = row_elems
         self.plan_slots = plan_slots
-        self.interpret = interpret
         self.trash = fast_capacity + num_pages  # reserved last row
         self.pool = jnp.zeros((self.trash + 1, row_elems), dtype)
         self.frame = np.full(num_pages, -1, np.int64)  # page -> frame row
@@ -95,9 +93,9 @@ class PagePool:
             src = np.arange(M, dtype=np.int32)
             dst = np.full(M, self.trash, np.int32)
             dst[: len(chunk)] = self.frame[chunk]
-            self.pool = page_copy(
+            self.pool = ops.page_copy(
                 jnp.asarray(staging, self.pool.dtype), self.pool,
-                jnp.asarray(src), jnp.asarray(dst), interpret=self.interpret,
+                jnp.asarray(src), jnp.asarray(dst),
             )
 
     def read_page(self, page_id: int) -> np.ndarray:
@@ -112,9 +110,9 @@ class PagePool:
         ``demote_ids``/``promote_ids`` are -1-padded id lists (the queue
         tick's drained lists, or an instant-mode plan's sides). Demotes are
         planned first so their vacated fast frames can serve as promote
-        destinations within the same ``page_move`` sweep — the sequential
-        grid reads every source row before a later step writes it (the
-        write-after-read contract ``tests/test_kernels.py`` locks).
+        destinations within the same ``page_move`` call — the kernel gathers
+        every source row before it writes any (the write-after-read contract
+        ``tests/test_kernels.py`` locks).
         """
         dem = np.asarray(demote_ids).ravel()
         pro = np.asarray(promote_ids).ravel()
@@ -172,9 +170,7 @@ class PagePool:
             d = np.full(M, self.trash, np.int32)
             s[: len(src[lo : lo + M])] = src[lo : lo + M]
             d[: len(dst[lo : lo + M])] = dst[lo : lo + M]
-            self.pool = page_move(
-                self.pool, jnp.asarray(s), jnp.asarray(d), interpret=self.interpret
-            )
+            self.pool = ops.page_move(self.pool, jnp.asarray(s), jnp.asarray(d))
         self._free_slow.extend(freed_slow)
         self.moved_pages += n
         return n

@@ -55,7 +55,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import policy
@@ -90,11 +89,10 @@ def fleet_multi_epoch(
     each machine's recorded backlog), ``[K, P]`` (each machine replays its
     row every epoch) or ``[K, k, P]``. Returns (fstate', plans, stats,
     flagged) with leaves shaped ``[K, k, ...]`` for the per-epoch outputs.
-    State buffers are donated on accelerator backends. ``trim_stats`` drops
-    the telemetry leaves the sweep record path never reads
-    (``policy._trim_stats``).
+    State buffers are donated. ``trim_stats`` drops the telemetry leaves
+    the sweep record path never reads (``policy._trim_stats``).
     """
-    return _jitted_fleet(policy._donate_state())(
+    return _jitted_fleet()(
         fstate, fparams, counts, k=k, max_tenants=max_tenants,
         plan_size=plan_size, exact_sampling=exact_sampling,
         count_clamp=count_clamp, collect_plans=collect_plans,
@@ -148,20 +146,20 @@ def _machine_updater():
 
 
 @lru_cache(maxsize=None)
-def _jitted_fleet(donate: bool):
+def _jitted_fleet():
     return jax.jit(
         _fleet_impl,
         static_argnames=(
             "k", "max_tenants", "plan_size", "exact_sampling", "count_clamp",
             "collect_plans", "trim_stats", "compile_sentinel",
         ),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,),
     )
 
 
 @lru_cache(maxsize=None)
 def _jitted_sharded_fleet(
-    mesh: Mesh, donate: bool, has_counts: bool, k: int, max_tenants: int,
+    mesh: Mesh, has_counts: bool, k: int, max_tenants: int,
     plan_size: int, exact_sampling: bool, count_clamp: int,
     collect_plans: bool, trim_stats: bool, compile_sentinel: bool = True,
 ):
@@ -171,7 +169,7 @@ def _jitted_sharded_fleet(
     ``PartitionSpec('machines')`` prefix partitions the whole pytree; the
     per-shard body is the plain vmapped scan, and since no collective
     crosses a machine slice the partitioning is communication-free
-    (``check_rep=False`` only disables the replication check shard_map
+    (``check_vma=False`` only disables the replication check shard_map
     would otherwise try to prove)."""
     impl = partial(
         _fleet_impl, k=k, max_tenants=max_tenants, plan_size=plan_size,
@@ -181,16 +179,16 @@ def _jitted_sharded_fleet(
     )
     spec = PartitionSpec("machines")
     if has_counts:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda s, p, c: impl(s, p, c), mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
         )
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda s, p: impl(s, p, None), mesh=mesh,
-            in_specs=(spec, spec), out_specs=spec, check_rep=False,
+            in_specs=(spec, spec), out_specs=spec, check_vma=False,
         )
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return jax.jit(fn, donate_argnums=(0,))
 
 
 def fleet_multi_epoch_sharded(
@@ -214,7 +212,7 @@ def fleet_multi_epoch_sharded(
     this by padding with inert machines. Per-machine rows are bit-identical
     to the unsharded path (no reduction crosses a machine slice)."""
     fn = _jitted_sharded_fleet(
-        mesh, policy._donate_state(), counts is not None, k, max_tenants,
+        mesh, counts is not None, k, max_tenants,
         plan_size, exact_sampling, count_clamp, collect_plans, trim_stats,
         compile_sentinel,
     )
@@ -253,9 +251,10 @@ class FleetMultiEpochResult:
 
 
 class DispatchError(RuntimeError):
-    """The fleet dispatch worker failed or timed out; the fleet state is
-    still the pre-dispatch one — ``FleetManager.recover_dispatch`` rolls the
-    epoch clocks back so the chunk can be retried (DESIGN.md §7)."""
+    """The fleet dispatch worker failed or timed out. Until the device
+    program launches, the fleet state is still the pre-dispatch one —
+    ``FleetManager.recover_dispatch`` rolls the epoch clocks back so the
+    chunk can be retried (DESIGN.md §7)."""
 
 
 class _DispatchWorker:
@@ -312,6 +311,29 @@ class _DispatchWorker:
             atexit.unregister(self.close)
         except Exception:
             pass
+
+
+class _DispatchGate:
+    """Decides, under a lock, whether a dispatch launches its device program
+    or was abandoned first: a launched program consumes the donated state,
+    so exactly one of the worker and ``recover_dispatch`` may own it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._state = "pending"
+
+    def _claim(self, to: str) -> bool:
+        with self._lock:
+            if self._state != "pending":
+                return False
+            self._state = to
+            return True
+
+    def start(self) -> bool:
+        return self._claim("started")
+
+    def abandon(self) -> bool:
+        return self._claim("abandoned")
 
 
 class FleetPendingResult:
@@ -460,6 +482,7 @@ class FleetManager:
         # naturally while the main thread keeps the host pipeline busy
         self._worker: Optional[_DispatchWorker] = None
         self._inflight = None
+        self._inflight_gate = None
         self._inflight_k = 0
         # first worker exception, noted at FAULT time by a done-callback —
         # every subsequent fleet operation raises it promptly instead of
@@ -636,6 +659,8 @@ class FleetManager:
             self._chaos_fail_n -= 1
         chaos_delay = self._chaos_delay_s
 
+        gate = _DispatchGate()
+
         def work():
             if hb is not None:
                 hb.beat(0)
@@ -643,6 +668,9 @@ class FleetManager:
                 time.sleep(chaos_delay)
             if chaos_fail:
                 raise RuntimeError("injected dispatch failure (chaos hook)")
+            if not gate.start():
+                # abandoned by recover_dispatch: the retry owns the stack
+                raise concurrent.futures.CancelledError()
             c = None
             if cn is not None:
                 # host->device upload of the workload happens in the worker
@@ -674,6 +702,7 @@ class FleetManager:
                 self._worker = _DispatchWorker()
             self._inflight = self._worker.submit(work)
             self._inflight.add_done_callback(self._note_dispatch_outcome)
+        self._inflight_gate = gate
         self._inflight_k = k
         self._park_slices()
         for m in self.machines:
@@ -694,14 +723,24 @@ class FleetManager:
 
     def recover_dispatch(self) -> None:
         """Reset after a failed (or wedged) dispatch so the chunk can be
-        retried. The stacked state is still the pre-dispatch assembly (the
-        CPU path never donates it), so recovery is: drop the in-flight
-        future, clear the sticky error, roll the per-machine epoch clocks
-        back by the dispatched k, and abandon the worker thread — a fresh
-        daemon is created on the next dispatch. A supervised fleet also gets
-        a fresh ``HeartbeatTracker`` (the old one latched the worker dead).
+        retried: drop the in-flight future, clear the sticky error, roll the
+        per-machine epoch clocks back by the dispatched k, and abandon the
+        worker thread — a fresh daemon is created on the next dispatch. A
+        supervised fleet also gets a fresh ``HeartbeatTracker`` (the old one
+        latched the worker dead).
+
+        The device program donates the stacked state, so a rollback is only
+        possible while that program has not been launched: the abandoned
+        worker is barred from launching it, and a dispatch that already
+        launched (finished or not) raises :class:`DispatchError` here
+        instead of retrying on consumed buffers.
         """
         if self._inflight is not None:
+            if not self._inflight_gate.abandon():
+                raise DispatchError(
+                    "the dispatch already launched its device program, which "
+                    "consumed the donated fleet state: no rollback"
+                )
             # flag before cancel: an abandoned-but-running future resolves
             # later and must not re-arm the sticky error we just cleared
             self._inflight._fleet_abandoned = True

@@ -20,7 +20,7 @@ candidate window: selection is exact for any number of candidates per tenant.
 Entry points:
   * ``policy_epoch``  — one epoch on explicit (pages, tenants, sampled).
   * ``epoch_step``    — fused sample -> policy -> apply on a ``PolicyState``
-                        (single dispatch; buffers donated off-CPU).
+                        (single dispatch; buffers donated).
   * ``multi_epoch``   — ``lax.scan`` of the epoch across k epochs in one
                         dispatch, with stacked per-epoch telemetry.
 """
@@ -71,15 +71,6 @@ from repro.core.types import (
 # (not full-width reductions), so the width costs two cumsums, not a
 # dozen O(T*C) passes.
 COUNT_CLAMP = 4096
-
-# Buffer donation saves a copy of the O(P) state arrays on accelerators; the
-# CPU backend cannot donate and would warn on every call. The decision is
-# made per call (not at import) so configuring the platform after importing
-# this module still does the right thing.
-def _donate_state() -> bool:
-    return jax.default_backend() != "cpu"
-
-
 
 
 def _per_tenant_pages(
@@ -902,14 +893,14 @@ def _epoch_step_impl(
 
 
 @lru_cache(maxsize=None)
-def _jitted_epoch_step(donate: bool):
+def _jitted_epoch_step():
     return jax.jit(
         _epoch_step_impl,
         static_argnames=(
             "max_tenants", "plan_size", "exact_sampling", "count_clamp",
             "compile_sentinel",
         ),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,),
     )
 
 
@@ -928,13 +919,13 @@ def epoch_step(
     Consumes ``state.pending`` (the access backlog) and the PRNG key carried
     in the state; returns (state', plan, stats) with ``pending`` zeroed and
     the migration already applied to the metadata. The state buffers are
-    donated on accelerator backends — do not reuse the argument there.
+    donated — do not reuse the argument.
     ``compile_sentinel=False`` omits the invariant-sentinel reductions from
     the program entirely (the reference point for the perf-gate overhead
     band); the default compiles them in, gated by the traced
     ``params.sentinel`` flag.
     """
-    return _jitted_epoch_step(_donate_state())(
+    return _jitted_epoch_step()(
         state, params, max_tenants=max_tenants, plan_size=plan_size,
         exact_sampling=exact_sampling, count_clamp=count_clamp,
         compile_sentinel=compile_sentinel,
@@ -1043,14 +1034,14 @@ def _multi_epoch_impl(
 
 
 @lru_cache(maxsize=None)
-def _jitted_multi_epoch(donate: bool):
+def _jitted_multi_epoch():
     return jax.jit(
         _multi_epoch_impl,
         static_argnames=(
             "k", "max_tenants", "plan_size", "exact_sampling", "count_clamp",
             "collect_plans", "trim_stats", "compile_sentinel",
         ),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,),
     )
 
 
@@ -1077,11 +1068,11 @@ def multi_epoch(
     every per-epoch output stacked on a leading k axis; ``plans`` is None
     when ``collect_plans=False`` (metadata-only simulation — the per-tenant
     promoted/demoted telemetry in ``stats`` is still exact). The state
-    buffers are donated on accelerator backends — do not reuse the argument
-    there. ``trim_stats=True`` drops the telemetry leaves the sweep record
-    path never reads (see :func:`_trim_stats`).
+    buffers are donated — do not reuse the argument. ``trim_stats=True``
+    drops the telemetry leaves the sweep record path never reads (see
+    :func:`_trim_stats`).
     """
-    return _jitted_multi_epoch(_donate_state())(
+    return _jitted_multi_epoch()(
         state, params, counts, k=k, max_tenants=max_tenants, plan_size=plan_size,
         exact_sampling=exact_sampling, count_clamp=count_clamp,
         collect_plans=collect_plans, trim_stats=trim_stats,

@@ -109,7 +109,7 @@ def flash_attention(
     sliding_window: int = 0,
     q_blk: int = 256,
     kv_blk: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, nh, Sq, dh = q.shape
     nkv, Skv = k.shape[1], k.shape[2]

@@ -50,11 +50,6 @@ def _hot_bins_kernel(
     out_bins_ref[...] = bins.astype(jnp.int32)
 
 
-def _default_interpret() -> bool:
-    """Compiled Pallas on TPU; interpreter everywhere else (CPU/GPU hosts)."""
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("num_bins", "tile", "n_chunk", "interpret"))
 def hot_bins(
     page_ids: jax.Array,  # [N] int32; entries < 0 ignored
@@ -63,15 +58,9 @@ def hot_bins(
     num_bins: int = 6,
     tile: int = 512,
     n_chunk: int = 1024,
-    interpret: bool = None,
+    interpret: bool,
 ):
-    """Returns (counts_out [P] i32, bins [P] i32).
-
-    ``interpret=None`` auto-selects from the JAX backend: the kernel runs
-    compiled on TPU and in the Pallas interpreter elsewhere.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
+    """Returns (counts_out [P] i32, bins [P] i32)."""
     P = counts_in.shape[0]
     N = page_ids.shape[0]
     pad_p = (-P) % tile

@@ -1,22 +1,16 @@
 """Public kernel entry points.
 
-Dispatch policy: on a real TPU backend the Pallas kernels compile natively
-(``interpret=False``); everywhere else (this CPU container, unit tests) they
-run in interpret mode, which executes the kernel body in Python — bit-level
-semantics, no Mosaic. The pure-jnp references in ``ref.py`` remain the
-correctness oracles either way.
-
-``use_pallas()`` may be forced via REPRO_FORCE_PALLAS=0/1 for experiments.
+Dispatch policy, decided here and nowhere else: on a TPU backend the Pallas
+kernels compile natively; on the CPU backend (tests, CI) they run in the
+Pallas interpreter, which executes the kernel body in Python with bit-level
+semantics. Any other backend is an error — there is no silent fallback to
+the interpreter or to the pure-jnp references in ``ref.py``, which stay the
+tests' correctness oracles.
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
 
-from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.hot_bins import hot_bins as _hot_bins
 from repro.kernels.page_copy import page_copy as _page_copy
@@ -24,54 +18,37 @@ from repro.kernels.page_copy import page_move as _page_move
 from repro.kernels.paged_attention import paged_attention as _paged
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def use_pallas() -> bool:
-    env = os.environ.get("REPRO_FORCE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    return True  # interpret mode on CPU, native on TPU
-
-
-def _interpret() -> bool:
-    return not on_tpu()
+def interpret() -> bool:
+    """False on TPU (compiled kernels), True on CPU (interpreter)."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels need a TPU or the CPU interpreter, not {backend!r}")
+    return backend == "cpu"
 
 
 def flash_attention(q, k, v, *, causal=True, sliding_window=0, q_blk=256, kv_blk=256):
-    if not use_pallas():
-        return _ref.flash_attention_ref(
-            q, k, v, causal=causal, sliding_window=sliding_window
-        )
     return _flash(
         q, k, v, causal=causal, sliding_window=sliding_window,
-        q_blk=q_blk, kv_blk=kv_blk, interpret=_interpret(),
+        q_blk=q_blk, kv_blk=kv_blk, interpret=interpret(),
     )
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
-    if not use_pallas():
-        return _ref.paged_attention_ref(q, k_pages, v_pages, block_tables, seq_lens)
-    return _paged(q, k_pages, v_pages, block_tables, seq_lens, interpret=_interpret())
+    return _paged(q, k_pages, v_pages, block_tables, seq_lens, interpret=interpret())
 
 
-def hot_bins(page_ids, counts_in, *, num_bins=6, tile=512):
-    if not use_pallas():
-        return _ref.hot_bins_ref(page_ids, counts_in, num_bins)
+def hot_bins(page_ids, counts_in, *, num_bins=6, tile=512, n_chunk=1024):
     return _hot_bins(
-        page_ids, counts_in, num_bins=num_bins, tile=tile, interpret=_interpret()
+        page_ids, counts_in, num_bins=num_bins, tile=tile, n_chunk=n_chunk,
+        interpret=interpret(),
     )
 
 
 def page_copy(src_pool, dst_pool, src_ids, dst_ids):
-    if not use_pallas():
-        return _ref.page_copy_ref(src_pool, dst_pool, src_ids, dst_ids)
-    return _page_copy(src_pool, dst_pool, src_ids, dst_ids, interpret=_interpret())
+    """Cross-pool row copies (staging -> pool writes)."""
+    return _page_copy(src_pool, dst_pool, src_ids, dst_ids, interpret=interpret())
 
 
 def page_move(pool, src_ids, dst_ids):
     """Intra-pool in-place moves (MaxMem migration executor path)."""
-    if not use_pallas():
-        return _ref.page_move_ref(pool, src_ids, dst_ids)
-    return _page_move(pool, src_ids, dst_ids, interpret=_interpret())
+    return _page_move(pool, src_ids, dst_ids, interpret=interpret())

@@ -91,7 +91,7 @@ def paged_attention(
     block_tables: jax.Array,  # [B, n_p] int32; -1 entries skipped
     seq_lens: jax.Array,  # [B] int32
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, nh, dh = q.shape
     P, page, nkv, _ = k_pages.shape

@@ -5,11 +5,13 @@ A *logical page* (what MaxMem tracks and migrates) is a block of
 both K and V — for yi-6b with 16-token pages that is ~0.5 MB, i.e. exactly a
 huge-page-sized migration unit (DESIGN.md §2).
 
-Physically, pools are [L, n_slots, page, nkv, dh] for K and V. Slots
-[0, n_fast) live in the fast tier (HBM), slots [n_fast, n_slots) in the slow
-tier (host memory via ``pinned_host`` on real TPU). ``slot_of`` maps logical
-page id -> physical slot; migration copies slot contents across the boundary
-and rewrites the mapping — block tables hold logical ids and never change.
+Physically, pools are [L, n_slots, page, nkv, dh] for K and V, in the
+model's compute dtype. Slots [0, n_fast) are the fast tier and slots
+[n_fast, n_slots) the slow tier; today both are slot ranges of one HBM
+array, and a slow tier in host memory is future work. ``slot_of`` maps
+logical page id -> physical slot; migration copies slot contents across the
+boundary and rewrites the mapping — block tables hold logical ids and never
+change.
 
 Page heat summaries (Quest-style per-page key min/max) ride along for the
 top-k page selector in the serving engine.
@@ -25,6 +27,7 @@ against the PREVIOUS owner's summaries, corrupting Quest top-k selection.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import List, Tuple
 
 import jax
@@ -36,6 +39,21 @@ from repro.core.types import TIER_FAST, TIER_SLOW, MigrationPlan
 from repro.kernels import ops
 
 
+@partial(jax.jit, donate_argnums=(0,))
+def _move_slots(pools, src, dst):
+    """Move slot ``src[i]`` to slot ``dst[i]`` in every layer of every
+    [L, n_slots, *row] pool, in place: one ``page_move`` per pool over its
+    [L * n_slots, *row] row view (row id = layer * n_slots + slot)."""
+    out = []
+    for pool in pools:
+        L, n = pool.shape[:2]
+        base = (jnp.arange(L, dtype=jnp.int32) * n)[:, None]
+        rows = pool.reshape(L * n, *pool.shape[2:])
+        rows = ops.page_move(rows, (base + src).reshape(-1), (base + dst).reshape(-1))
+        out.append(rows.reshape(pool.shape))
+    return tuple(out)
+
+
 class TieredPagedKV:
     def __init__(
         self,
@@ -43,15 +61,14 @@ class TieredPagedKV:
         n_fast_slots: int,
         n_slow_slots: int,
         page_tokens: int = 16,
-        dtype=jnp.float32,
     ):
         self.cfg = cfg
         self.page = page_tokens
         self.n_fast = n_fast_slots
         self.n_slots = n_fast_slots + n_slow_slots
         L, nkv, dh = cfg.num_layers, cfg.num_kv_heads, cfg.d_head
-        self.k_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), dtype)
-        self.v_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), dtype)
+        self.k_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), cfg.cdtype)
+        self.v_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), cfg.cdtype)
         # Quest summaries (per layer): elementwise min/max of keys in the page
         self.k_max = jnp.full((L, self.n_slots, nkv, dh), -jnp.inf, jnp.float32)
         self.k_min = jnp.full((L, self.n_slots, nkv, dh), jnp.inf, jnp.float32)
@@ -191,27 +208,16 @@ class TieredPagedKV:
         if not moves_src:
             return 0
 
-        src = jnp.asarray(moves_src, jnp.int32)
-        dst = jnp.asarray(moves_dst, jnp.int32)
-        L = self.cfg.num_layers
-        n = self.n_slots
-        # expand page moves across layers: row id = l * n_slots + slot
-        src_all = (jnp.arange(L)[:, None] * n + src[None, :]).reshape(-1)
-        dst_all = (jnp.arange(L)[:, None] * n + dst[None, :]).reshape(-1)
-        E = int(np.prod(self.k_pool.shape[2:]))
-        self.k_pool = ops.page_move(
-            self.k_pool.reshape(L * n, E), src_all, dst_all
-        ).reshape(self.k_pool.shape)
-        self.v_pool = ops.page_move(
-            self.v_pool.reshape(L * n, E), src_all, dst_all
-        ).reshape(self.v_pool.shape)
-        Es = int(np.prod(self.k_max.shape[2:]))
-        self.k_max = ops.page_move(
-            self.k_max.reshape(L * n, Es), src_all, dst_all
-        ).reshape(self.k_max.shape)
-        self.k_min = ops.page_move(
-            self.k_min.reshape(L * n, Es), src_all, dst_all
-        ).reshape(self.k_min.shape)
+        # pad to a power of two so plan sizes reuse a few compiled programs;
+        # the pad repeats move 0, whose duplicate gathers read the same
+        # pre-plan row and write the same bytes
+        m = len(moves_src)
+        pad = (1 << (m - 1).bit_length()) - m
+        self.k_pool, self.v_pool, self.k_max, self.k_min = _move_slots(
+            (self.k_pool, self.v_pool, self.k_max, self.k_min),
+            jnp.asarray(moves_src + moves_src[:1] * pad, jnp.int32),
+            jnp.asarray(moves_dst + moves_dst[:1] * pad, jnp.int32),
+        )
         # page_move is a gather: a swapped-out source row keeps a stale COPY
         # of the migrated page's data. Any such row now held by a free
         # logical page must be re-scrubbed or the free/reuse invariant

@@ -7,6 +7,7 @@ touch jax device state — device counts are locked at first jax init, and only
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,11 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     cross-pod data-parallel axis (DCN-connected in a real deployment)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(*, multi_pod: bool = False):
     """Small-device-count mesh with the same axis names (CI smoke)."""
     shape = (2, 2, 4) if multi_pod else (4, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

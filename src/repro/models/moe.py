@@ -156,7 +156,6 @@ def moe_mlp(params: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
 def moe_mlp_shardmap(
     params: Params, x: jax.Array, cfg, mesh, rules
 ) -> Tuple[jax.Array, jax.Array]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -235,7 +234,7 @@ def moe_mlp_shardmap(
     sg = sp["w_gate"] if sp else jnp.zeros((d, 0), x.dtype)
     su = sp["w_up"] if sp else jnp.zeros((d, 0), x.dtype)
     sd = sp["w_down"] if sp else jnp.zeros((0, d), x.dtype)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -243,7 +242,7 @@ def moe_mlp_shardmap(
             sg_spec, sg_spec, sd_spec,
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(
         x, params["router"],
         params["w_gate"], params["w_up"], params["w_down"],

@@ -29,6 +29,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -87,6 +88,7 @@ class ServingEngine:
         self.manager = manager
         self.kv = kv
         self.api = get_model(cfg)
+        self._prefill = jax.jit(self.api.prefill, static_argnums=(2,))
         self.max_batch = max_batch
         self.n_p = pages_per_seq
         self.quest_pages = quest_pages
@@ -166,7 +168,7 @@ class ServingEngine:
             self.tables[lane, :] = -1
             self.tables[lane, :n_pages] = req.pages
             # Prefill: dense forward collecting KV, then scatter into pages.
-            logits, cache = self.api.prefill(
+            logits, cache = self._prefill(
                 self.params, jnp.asarray(req.prompt[None, :]), S
             )
             k, v = cache.k, cache.v  # [L, 1, S, nkv, dh]

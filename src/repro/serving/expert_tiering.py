@@ -132,14 +132,8 @@ class ExpertTierManager:
             return 0
         sidx = jnp.asarray(src, jnp.int32)
         didx = jnp.asarray(dst, jnp.int32)
-        p = self.pools
         self.pools = ExpertPools(
-            w_gate=ops.page_move(p.w_gate.reshape(self.n_slots, -1), sidx, didx
-                                 ).reshape(p.w_gate.shape),
-            w_up=ops.page_move(p.w_up.reshape(self.n_slots, -1), sidx, didx
-                               ).reshape(p.w_up.shape),
-            w_down=ops.page_move(p.w_down.reshape(self.n_slots, -1), sidx, didx
-                                 ).reshape(p.w_down.shape),
+            *(ops.page_move(w, sidx, didx) for w in self.pools)
         )
         return len(src)
 

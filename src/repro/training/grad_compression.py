@@ -58,8 +58,6 @@ def shardmap_int8_psum(mesh, axis_names: Tuple[str, ...]):
     Usage (launch layer): reduce = shardmap_int8_psum(mesh, ("data",));
     g = reduce(g)  # g replicated over data axis afterwards.
     """
-    from jax.experimental.shard_map import shard_map
-
     def reduce_fn(x):
         q, scale = _quant(x)
         qs = jax.lax.psum(q.astype(jnp.int32), axis_names)  # int32 accum
@@ -70,7 +68,7 @@ def shardmap_int8_psum(mesh, axis_names: Tuple[str, ...]):
         return qs.astype(jnp.float32) * s / n
 
     def apply(x):
-        return shard_map(
+        return jax.shard_map(
             reduce_fn,
             mesh=mesh,
             in_specs=P(*axis_names),

@@ -146,3 +146,33 @@ class TestInvariants:
             # owners never change due to migration
             for h in handles:
                 assert (np.asarray(m.pages.owner)[pages[h]] == int(h)).all()
+
+
+class TestDataPlaneDispatch:
+    def test_page_pool_reaches_kernels_through_ops(self, monkeypatch):
+        """The pool's writes and migrations go through ``kernels.ops``, the
+        one place that picks compiled (TPU) or interpreted (CPU) kernels."""
+        from repro.kernels import ops
+
+        calls = []
+        for name in ("page_copy", "page_move"):
+            real = getattr(ops, name)
+            monkeypatch.setattr(
+                ops, name,
+                lambda *a, _real=real, _name=name: calls.append(_name) or _real(*a),
+            )
+        m = _mgr(queue_size=32, data_plane_elems=16)
+        fast_h = m.register(t_miss=1.0)
+        slow_h = m.register(t_miss=0.1)
+        m.allocate(fast_h, 64)
+        hot = m.allocate(slow_h, 64)
+        rows = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
+        m.pool.write_pages(hot, rows)
+        counts = np.zeros(256, np.int64)
+        counts[hot] = 50
+        m.run_epochs(4, counts=counts)
+        assert "page_copy" in calls and "page_move" in calls
+        assert m.pool.moved_pages > 0
+        m.pool.check(m.tiers())
+        got = np.stack([m.pool.read_page(p) for p in hot])
+        assert (got == rows).all()

@@ -1,6 +1,6 @@
 """Pallas kernel sweeps: shapes x dtypes, assert_allclose vs ref.py oracles.
 
-Kernels run in interpret mode (CPU container); the oracle is pure jnp.
+Kernels run through ``ops`` (the interpreter on the CPU); the oracle is pure jnp.
 """
 import jax
 import jax.numpy as jnp
@@ -13,10 +13,7 @@ except ImportError:  # clean checkout: deterministic fallback sweep
     from _hypothesis_fallback import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.hot_bins import hot_bins
-from repro.kernels.page_copy import page_copy
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import flash_attention, hot_bins, page_copy, page_move, paged_attention
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -119,17 +116,18 @@ class TestHotBins:
         assert (np.asarray(b) == np.asarray(br)).all()
 
     def test_interpret_auto_selects_from_backend(self):
-        """interpret=None compiles on TPU and interprets elsewhere; the
-        result must be identical either way."""
+        """ops compiles on TPU and interprets on the CPU; the result must be
+        identical to an explicit call either way."""
         from repro.kernels import hot_bins as hb
+        from repro.kernels import ops
 
         expect = jax.default_backend() != "tpu"
-        assert hb._default_interpret() == expect
+        assert ops.interpret() == expect
         rng = np.random.default_rng(0)
         ids = jnp.asarray(rng.integers(-1, 130, 257), jnp.int32)
         cin = jnp.asarray(rng.integers(0, 40, 130), jnp.int32)
         c_auto, b_auto = hot_bins(ids, cin, tile=64, n_chunk=128)
-        c_exp, b_exp = hot_bins(ids, cin, tile=64, n_chunk=128, interpret=expect)
+        c_exp, b_exp = hb.hot_bins(ids, cin, tile=64, n_chunk=128, interpret=expect)
         assert (np.asarray(c_auto) == np.asarray(c_exp)).all()
         assert (np.asarray(b_auto) == np.asarray(b_exp)).all()
 
@@ -224,15 +222,14 @@ class TestPageCopy:
         assert (out[4] == src_np[5]).all()
         keep = [0, 2, 3, 5, 6, 7, 8]
         assert (out[keep] == dst_np[keep]).all()
-        # trash row holds the LAST padded source (sequential grid) — its
-        # content is unspecified by the contract, only its isolation matters
+        # the interpreter issues the DMAs in order, so the trash row holds
+        # the LAST padded source; on the chip its content is unspecified by
+        # the contract, only its isolation matters
         assert (out[trash] == src_np[1]).all()
 
 
 class TestPageMove:
     def test_intra_pool_moves_match_ref(self):
-        from repro.kernels.page_copy import page_move
-
         rng = np.random.default_rng(5)
         pool = jnp.asarray(rng.normal(size=(16, 64)), jnp.float32)
         sid = jnp.asarray([0, 1, 2], jnp.int32)
@@ -243,8 +240,6 @@ class TestPageMove:
 
     def test_write_after_read_is_safe(self):
         """A plan may WRITE a row that an earlier step READ (slot reuse)."""
-        from repro.kernels.page_copy import page_move
-
         pool = jnp.asarray(np.arange(8 * 4).reshape(8, 4), jnp.float32)
         # demote: row1 -> row6 (reads 1), promote: row5 -> row1 (writes 1)
         sid = jnp.asarray([1, 5], jnp.int32)
@@ -255,8 +250,6 @@ class TestPageMove:
 
     @pytest.mark.parametrize("Pr,E,M", [(11, 100, 4), (9, 257, 5), (5, 33, 3)])
     def test_non_multiple_of_tile_sizes(self, Pr, E, M):
-        from repro.kernels.page_copy import page_move
-
         rng = np.random.default_rng(Pr * 7 + E)
         pool = jnp.asarray(rng.normal(size=(Pr, E)), jnp.float32)
         sid = jnp.asarray(rng.choice(Pr - 1, M, replace=False), jnp.int32)
@@ -270,8 +263,6 @@ class TestPageMove:
     def test_trash_row_padding_contract(self):
         """The data plane pads intra-pool plans with trash->trash self-copy
         entries; real rows must be untouched by the padding."""
-        from repro.kernels.page_copy import page_move
-
         rng = np.random.default_rng(1)
         pool = jnp.asarray(rng.normal(size=(8, 48)), jnp.float32)
         trash = 7

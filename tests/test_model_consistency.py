@@ -1,6 +1,7 @@
 """Cross-path model consistency: prefill vs decode, shard_map MoE vs pjit
 MoE, deferred vs eager cache commit, hybrid state handoff."""
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -75,7 +76,7 @@ def test_moe_shardmap_matches_pjit_path_on_unit_mesh():
         "tokens": jnp.asarray(np.arange(1, 33)[None, :], jnp.int32),
         "labels": jnp.asarray(np.arange(2, 34)[None, :], jnp.int32),
     }
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = rules_for(cfg, mesh)
 
     with tuning.tuned(moe_shardmap=False):

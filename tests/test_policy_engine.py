@@ -169,7 +169,8 @@ class TestMultiEpoch:
         state0, params, rng = self._state()
         counts = jnp.asarray(rng.integers(0, 20, 256), jnp.uint32)
         k = 6
-        st = state0
+        # both paths donate their state: each starts from its own copy
+        st = jax.tree.map(jnp.copy, state0)
         seq_stats = []
         for _ in range(k):
             st = st._replace(pending=st.pending + counts)
@@ -199,7 +200,7 @@ class TestMultiEpoch:
         state0, params, rng = self._state(seed=5)
         counts = jnp.asarray(rng.integers(0, 20, 256), jnp.uint32)
         _, plans_a, stats_a, _ = policy.multi_epoch(
-            state0, params, counts, k=4, max_tenants=4, plan_size=16,
+            jax.tree.map(jnp.copy, state0), params, counts, k=4, max_tenants=4, plan_size=16,
             exact_sampling=True, collect_plans=True,
         )
         _, plans_b, stats_b, _ = policy.multi_epoch(

@@ -18,6 +18,7 @@ Plus the scaling-bench scaffolding: the geometry-parameterized
 ``scale_colocation`` scenario, the log-log slope fit, and the fleet
 ``live_bytes`` accounting.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -98,8 +99,9 @@ def test_full_epoch_identical_across_tiling_threshold():
 
     def one_epoch():
         policy._jitted_epoch_step.cache_clear()  # drop the cached jit trace
+        # the tick donates its state: hand each run its own copy
         s2, plan, stats = policy.epoch_step(
-            st, params, max_tenants=T, plan_size=R)
+            jax.tree.map(jnp.copy, st), params, max_tenants=T, plan_size=R)
         return (
             np.asarray(s2.pages.tier), np.asarray(s2.pages.count),
             np.asarray(plan.promote), np.asarray(plan.demote),

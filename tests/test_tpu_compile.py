@@ -1,0 +1,65 @@
+"""The main path's Pallas kernels compile for a TPU v5e at their real widths.
+
+Nothing runs: the TPU compiler installed with JAX compiles each kernel for a
+described (not attached) v5e chip, which refuses what interpret mode
+accepts — block shapes off the (8, 128) tiling, slices inside a tile. The
+topology is described inside a fixture, never while a module is imported,
+so every test worker collects the same tests and only the worker that runs
+this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.page_copy import page_copy, page_move
+
+QWEN = get_config("qwen2.5-3b")
+KV_ROWS = QWEN.num_layers * 1024  # one row per (layer, slot) of a 1024-slot pool
+DATA_PLANE_ROWS = 458_752 + 65_536 + 1  # paper geometry: slow + fast + trash
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, args):
+    text = fn.lower(*args, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the pool is aliased in place: no relayout copy of it around the kernel
+    assert " copy(" not in text
+
+
+@pytest.mark.parametrize("name,row,dtype,m", [
+    ("data_plane", (128,), jnp.float32, 2048),
+    ("qwen_kv", (16, QWEN.num_kv_heads, QWEN.d_head), jnp.bfloat16, QWEN.num_layers * 64),
+    ("qwen_quest_summary", (QWEN.num_kv_heads, QWEN.d_head), jnp.float32, QWEN.num_layers * 64),
+])
+def test_page_move_compiles(one_chip, name, row, dtype, m):
+    rows = DATA_PLANE_ROWS if name == "data_plane" else KV_ROWS
+    pool = jax.ShapeDtypeStruct((rows, *row), dtype, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    _compile(page_move, (pool, ids, ids))
+
+
+def test_page_copy_compiles_at_data_plane_width(one_chip):
+    m = 2048
+    staging = jax.ShapeDtypeStruct((m, 128), jnp.float32, sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((DATA_PLANE_ROWS, 128), jnp.float32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    _compile(page_copy, (staging, pool, ids, ids))
