@@ -20,11 +20,16 @@ bookkeeping, while every data movement goes through the Pallas kernels:
 drained id lists from each epoch's queue tick (or the instant-apply plan),
 so simulated placements and actual page bytes can never diverge — which is
 what the data-integrity tests assert.
+
+``on_allocate``, ``on_free``, ``write_pages`` and ``execute`` each sit in one
+``maxmem.pool.<method>`` profiler span per call (see core/manager.py).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -66,12 +71,14 @@ class PagePool:
         self.fault_injector = injector
 
     # ------------------------------------------------------------ control
+    @partial(jax.profiler.annotate_function, name="maxmem.pool.on_allocate")
     def on_allocate(self, page_ids: Sequence[int], tiers: Sequence[int]) -> None:
         """Assign a frame (in the page's tier) to each newly allocated page."""
         for p, t in zip(np.asarray(page_ids), np.asarray(tiers)):
             free = self._free_fast if t == TIER_FAST else self._free_slow
             self.frame[p] = free.pop()
 
+    @partial(jax.profiler.annotate_function, name="maxmem.pool.on_free")
     def on_free(self, page_ids: Sequence[int]) -> None:
         for p in np.asarray(page_ids):
             f = int(self.frame[p])
@@ -81,6 +88,7 @@ class PagePool:
             self.frame[p] = -1
 
     # --------------------------------------------------------------- data
+    @partial(jax.profiler.annotate_function, name="maxmem.pool.write_pages")
     def write_pages(self, page_ids: Sequence[int], rows: np.ndarray) -> None:
         """DMA tenant data into page frames (staging -> pool, page_copy)."""
         ids = np.asarray(page_ids, np.int64)
@@ -104,6 +112,7 @@ class PagePool:
         return np.asarray(self.pool[f])
 
     # ---------------------------------------------------------- migration
+    @partial(jax.profiler.annotate_function, name="maxmem.pool.execute")
     def execute(self, demote_ids, promote_ids) -> int:
         """Move drained pages across tiers; returns pages moved.
 

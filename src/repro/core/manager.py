@@ -24,10 +24,22 @@ reports with a jitted add, ``run_epoch`` is one fused dispatch
 so a burst of ``fast_pages_of``/``tier_of`` calls costs one transfer.
 Control-plane operations (register/allocate/free) stay host-side — they are
 rare and inherently serial.
+
+Each host-side step sits in a ``jax.profiler.TraceAnnotation`` span named
+``maxmem.<step>``, one per call, on the profiler's clock beside the device
+ops: ``run_epoch`` (carrying ``epoch``, the manager's ``epoch_index``) and,
+nested in it, ``segs`` (only when the owner segments need a rebuild),
+``dispatch`` (the fused tick's call), ``fetch`` (each blocking device->host
+read) and the pool's ``pool.execute``; ``record_access``; ``register``,
+``unregister``, ``allocate`` and ``free``; ``snapshot`` (only on a miss of
+the cached host snapshot); and the pool's ``pool.on_allocate``,
+``pool.on_free`` and ``pool.write_pages`` (core/dataplane.py). A span costs
+about a microsecond when no profiler runs.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -51,6 +63,12 @@ from repro.core.types import (
     segments_build_host,
     segments_update_host,
 )
+
+
+def _fetch(x) -> np.ndarray:
+    """A blocking device->host read, in its own ``maxmem.fetch`` span."""
+    with jax.profiler.TraceAnnotation("maxmem.fetch"):
+        return np.asarray(x)
 
 
 class TenantHandle(int):
@@ -369,6 +387,10 @@ class CentralManager:
     def _ensure_segs(self) -> None:
         if self._segs_owner is None:
             return
+        with jax.profiler.TraceAnnotation("maxmem.segs"):
+            self._rebuild_segs()
+
+    def _rebuild_segs(self) -> None:
         cur = self._segs_owner
         T = self.max_tenants
         host = None
@@ -415,11 +437,13 @@ class CentralManager:
         """Host copy of the page metadata; ONE batched transfer per epoch no
         matter how many telemetry reads follow."""
         if self._snap is None:
-            tier, owner = jax.device_get((self._state.pages.tier, self._state.pages.owner))
+            with jax.profiler.TraceAnnotation("maxmem.snapshot"):
+                tier, owner = jax.device_get((self._state.pages.tier, self._state.pages.owner))
             self._snap = {"tier": tier, "owner": owner}
         return self._snap
 
     # ------------------------------------------------------------- tenants
+    @partial(jax.profiler.annotate_function, name="maxmem.register")
     def register(self, t_miss: float) -> TenantHandle:
         assert 0.0 < t_miss <= 1.0, "t_miss must be in (0, 1] (§3.1)"
         active = np.asarray(self.tenants.active)
@@ -445,6 +469,7 @@ class CentralManager:
             t_miss=self.tenants.t_miss.at[int(h)].set(t_miss)
         )
 
+    @partial(jax.profiler.annotate_function, name="maxmem.unregister")
     def unregister(self, h: TenantHandle) -> None:
         owned = np.flatnonzero(self._snapshot()["owner"] == int(h))
         if len(owned):
@@ -455,6 +480,7 @@ class CentralManager:
         self.tenants = self.tenants.clear_slot(int(h))
 
     # ------------------------------------------------------------- memory
+    @partial(jax.profiler.annotate_function, name="maxmem.allocate")
     def allocate(self, h: TenantHandle, n_pages: int) -> np.ndarray:
         """First-touch allocation: fast while available, then slow (§3.1)."""
         snap = self._snapshot()
@@ -483,6 +509,7 @@ class CentralManager:
             self.pool.on_allocate(take, new_tier[take])
         return take
 
+    @partial(jax.profiler.annotate_function, name="maxmem.free")
     def free(self, h: TenantHandle, page_ids: Sequence[int]) -> None:
         ids = np.asarray(page_ids, np.int32)
         snap = self._snapshot()
@@ -540,6 +567,7 @@ class CentralManager:
             self.pool.on_free(ids)
 
     # ------------------------------------------------------------- accesses
+    @partial(jax.profiler.annotate_function, name="maxmem.record_access")
     def record_access(self, counts: np.ndarray) -> None:
         """Engine-side access report: exact per-page access counts since the
         last call (the instrumented attention/GUPS stream). Folded into the
@@ -551,12 +579,12 @@ class CentralManager:
 
     # ------------------------------------------------------------- epoch
     def _fold_queue_stats(self, q) -> None:
-        self.queue_enqueued += int(np.asarray(q.enqueued).sum())
+        self.queue_enqueued += int(_fetch(q.enqueued).sum())
         self.queue_drained += int(
-            np.asarray(q.drained_promote).sum() + np.asarray(q.drained_demote).sum()
+            _fetch(q.drained_promote).sum() + _fetch(q.drained_demote).sum()
         )
-        self.queue_cancelled += int(np.asarray(q.cancelled).sum())
-        self.queue_dropped += int(np.asarray(q.dropped).sum())
+        self.queue_cancelled += int(_fetch(q.cancelled).sum())
+        self.queue_dropped += int(_fetch(q.dropped).sum())
 
     def _pool_execute(self, dem_ids, pro_ids, failed_dem: set, failed_pro: set) -> None:
         """Run one drained batch through the pool, folding fault outcomes.
@@ -599,29 +627,32 @@ class CentralManager:
 
     def run_epoch(self) -> EpochResult:
         """Policy-thread tick: sample -> policy -> migrate, one dispatch."""
-        self._ensure_segs()
-        self._state, plan, stats = policy.epoch_step(
-            self._state,
-            self.params,
-            max_tenants=self.max_tenants,
-            plan_size=self.plan_size,
-            exact_sampling=self.exact_sampling,
-        )
-        self.epoch_index += 1
-        self._snap = None
-        fd, fp = set(), set()
-        if stats.queue is not None:
-            self._fold_queue_stats(stats.queue)
-            if self.pool is not None:
-                self._pool_execute(
-                    np.asarray(stats.queue.drained_demote_ids),
-                    np.asarray(stats.queue.drained_promote_ids),
-                    fd, fp,
+        with jax.profiler.TraceAnnotation("maxmem.run_epoch", epoch=self.epoch_index):
+            self._ensure_segs()
+            with jax.profiler.TraceAnnotation("maxmem.dispatch"):
+                self._state, plan, stats = policy.epoch_step(
+                    self._state,
+                    self.params,
+                    max_tenants=self.max_tenants,
+                    plan_size=self.plan_size,
+                    exact_sampling=self.exact_sampling,
                 )
-        elif self.pool is not None:
-            self._pool_execute(np.asarray(plan.demote), np.asarray(plan.promote), fd, fp)
-        self._revert_failed_moves(fd, fp)
-        return EpochResult(stats=stats, plan=plan, flags=np.asarray(self._state.tenants.flagged))
+            self.epoch_index += 1
+            self._snap = None
+            fd, fp = set(), set()
+            if stats.queue is not None:
+                self._fold_queue_stats(stats.queue)
+                if self.pool is not None:
+                    self._pool_execute(
+                        _fetch(stats.queue.drained_demote_ids),
+                        _fetch(stats.queue.drained_promote_ids),
+                        fd, fp,
+                    )
+            elif self.pool is not None:
+                self._pool_execute(_fetch(plan.demote), _fetch(plan.promote), fd, fp)
+            self._revert_failed_moves(fd, fp)
+            flags = _fetch(self._state.tenants.flagged)
+        return EpochResult(stats=stats, plan=plan, flags=flags)
 
     def run_epochs(
         self,

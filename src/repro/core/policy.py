@@ -331,177 +331,182 @@ def _epoch_core(
     P = pages.owner.shape[0]
     T = max_tenants
     C = count_clamp
-    # Per-tenant reductions: owner-segment cumsums when the state carries
-    # the sorted permutation (manager-built states), else a [T, P] one-hot.
-    oh = None
-    if segs is None:
-        oh = pages.owner[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]  # [T,P]
+    with jax.named_scope("tick.bins"):
+        # Per-tenant reductions: owner-segment cumsums when the state carries
+        # the sorted permutation (manager-built states), else a [T, P] one-hot.
+        oh = None
+        if segs is None:
+            oh = pages.owner[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]  # [T,P]
 
-    # ---- 1. per-tenant fast/slow sample counts (tier *before* migration) ----
-    is_fast = pages.tier == TIER_FAST
-    is_slow = pages.tier == TIER_SLOW
-    # owner is stored i16 (packed layouts, types.py); every slot-arithmetic
-    # consumer below (flat histogram keys, T + owner offsets) needs i32
-    # range, so upcast ONCE here — one fused elementwise pass
-    owner32 = pages.owner.astype(jnp.int32)
-    if segs is not None:
-        # one [2T+1] scatter-add replaces the two global segment cumsums
-        # plus their sorted-order gathers (measurably faster under both
-        # XLA:CPU runtimes); u32 adds are associative mod 2^32, so the
-        # per-tenant totals are bit-identical to the cumsum path whatever
-        # the accumulation order (owned pages are always fast or slow:
-        # allocate/free set owner and tier together, so fast|slow covers
-        # every owned page exactly once)
-        T2 = max_tenants
-        own_ok = pages.owner >= 0
-        idx = jnp.where(
-            own_ok & is_fast, owner32,
-            jnp.where(own_ok, T2 + owner32, 2 * T2),
-        )
-        tbl = jnp.zeros((2 * T2 + 1,), jnp.uint32).at[idx].add(
-            sampled.astype(jnp.uint32), mode="drop"
-        )
-        s_fast = tbl[:T2]
-        s_slow = tbl[T2 : 2 * T2]
-    else:
-        s_fast = jnp.where(oh & is_fast[None, :], sampled[None, :], 0).sum(axis=1)
-        s_slow = jnp.where(oh & is_slow[None, :], sampled[None, :], 0).sum(axis=1)
-    pages, tenants, cooled, eff = bins.accumulate_and_count(
-        pages, tenants, sampled, params.num_bins, owner_onehot=oh, segs=segs
-    )
-
-    # ---- 2. FMMR update ------------------------------------------------------
-    now = fmmr.fmmr_now(s_fast.astype(jnp.float32), s_slow.astype(jnp.float32))
-    ewma = fmmr.update_ewma(tenants.a_miss, now, params.ewma_lambda)
-    ewma = jnp.where(tenants.active, ewma, 0.0)
-    tenants = tenants._replace(a_miss=ewma)
-
-    # ---- per-(tenant, tier, clamped count) candidate histograms --------------
-    # ONE P-element scatter; everything below — holdings, candidate totals,
-    # rebalance pair counts, victim cutoffs — reads off these two tables and
-    # their prefix sums.
-    is_owned = pages.owner >= 0
-    owner = jnp.maximum(owner32, 0)
-    slow_cand = is_owned & is_slow
-    fast_cand = is_owned & is_fast
-    if exclude is not None:
-        slow_cand = slow_cand & ~exclude
-        fast_cand = fast_cand & ~exclude
-    key = jnp.minimum(eff.astype(jnp.int32), C - 1)
-    flat = jnp.where(
-        slow_cand,
-        owner * C + key,
-        jnp.where(fast_cand, T * C + owner * C + key, 2 * T * C),
-    )
-    hist2 = jnp.zeros((2 * T * C + 1,), jnp.int32).at[flat].add(1, mode="drop")
-    hist_slow = hist2[: T * C].reshape(T, C)
-    hist_fast = hist2[T * C : 2 * T * C].reshape(T, C)
-    # tiled past 64k-element rows — at [256, 4096] the row scans alone cost
-    # ~20 ms untiled (core/tiling.py; bit-identical integer addition)
-    cum_slow = tiled_cumsum(hist_slow, axis=1)  # [T,C] candidates with count <= c
-    cum_fast = tiled_cumsum(hist_fast, axis=1)
-    n_slow_cand = cum_slow[:, -1]  # == per-tenant slow-page holdings
-    n_fast_cand = cum_fast[:, -1]  # == per-tenant fast-page holdings
-    if exclude is None:
-        fast_hold, slow_hold = n_fast_cand, n_slow_cand
-    else:
-        # in-flight pages are excluded from the candidate histograms but
-        # still occupy their source tier: holdings must count them
-        fast_hold, slow_hold = _per_tenant_pages(
-            pages, max_tenants, segs=segs, owner_onehot=oh
+        # ---- 1. per-tenant fast/slow sample counts (tier *before* migration) ----
+        is_fast = pages.tier == TIER_FAST
+        is_slow = pages.tier == TIER_SLOW
+        # owner is stored i16 (packed layouts, types.py); every slot-arithmetic
+        # consumer below (flat histogram keys, T + owner offsets) needs i32
+        # range, so upcast ONCE here — one fused elementwise pass
+        owner32 = pages.owner.astype(jnp.int32)
+        if segs is not None:
+            # one [2T+1] scatter-add replaces the two global segment cumsums
+            # plus their sorted-order gathers (measurably faster under both
+            # XLA:CPU runtimes); u32 adds are associative mod 2^32, so the
+            # per-tenant totals are bit-identical to the cumsum path whatever
+            # the accumulation order (owned pages are always fast or slow:
+            # allocate/free set owner and tier together, so fast|slow covers
+            # every owned page exactly once)
+            T2 = max_tenants
+            own_ok = pages.owner >= 0
+            idx = jnp.where(
+                own_ok & is_fast, owner32,
+                jnp.where(own_ok, T2 + owner32, 2 * T2),
+            )
+            tbl = jnp.zeros((2 * T2 + 1,), jnp.uint32).at[idx].add(
+                sampled.astype(jnp.uint32), mode="drop"
+            )
+            s_fast = tbl[:T2]
+            s_slow = tbl[T2 : 2 * T2]
+        else:
+            s_fast = jnp.where(oh & is_fast[None, :], sampled[None, :], 0).sum(axis=1)
+            s_slow = jnp.where(oh & is_slow[None, :], sampled[None, :], 0).sum(axis=1)
+        pages, tenants, cooled, eff = bins.accumulate_and_count(
+            pages, tenants, sampled, params.num_bins, owner_onehot=oh, segs=segs
         )
 
-    # ---- 3. proportional reallocation (budget R/2) ---------------------------
-    # alloc_headroom fast pages are reserved for first-touch allocation
-    # (DESIGN.md §8): the policy never promotes into them, so a new page's
-    # allocation can land fast instead of waiting an epoch for promotion.
-    # Allocations may transiently consume the reserve (holdings then exceed
-    # the promotion ceiling) — clamp at zero rather than forcing net
-    # demotions; request churn regenerates the headroom on free.
-    free_fast = jnp.maximum(
-        params.fast_capacity - params.alloc_headroom - fast_hold.sum(), 0
-    )
-    realloc_budget = params.migration_budget // 2
-    # asymmetric hysteresis guards: negative band = inherit the symmetric
-    # ``hysteresis`` value, which keeps the default program bit-identical
-    band_need = jnp.where(
-        params.promote_band >= 0, params.promote_band, params.hysteresis
-    )
-    band_donor = jnp.where(
-        params.demote_band >= 0, params.demote_band, params.hysteresis
-    )
-    ra = fmmr.reallocate(
-        tenants, fast_hold, free_fast, realloc_budget,
-        fair_mode=params.fair_mode, hysteresis=params.hysteresis,
-        need_band=band_need, donor_band=band_donor,
-    )
-    tenants = tenants._replace(flagged=ra.flagged)
-    # the R/2 reallocation budget counts BOTH promotions and the demotions
-    # that make room for them: rescale if gives+takes overshoot.
-    ra_moves = ra.give.sum() + ra.take.sum()
-    ra_scale = jnp.where(
-        ra_moves > realloc_budget,
-        realloc_budget.astype(jnp.float32) / jnp.maximum(ra_moves, 1),
-        1.0,
-    )
-    take2 = jnp.floor(ra.take * ra_scale).astype(jnp.int32)
-    give2 = jnp.floor(ra.give * ra_scale).astype(jnp.int32)
-    # integer flooring can break gives <= free + takes: FCFS re-clamp
-    give2 = fmmr.clamp_gives(give2, tenants.arrival, free_fast + take2.sum())
-    ra = ra._replace(give=give2, take=take2)
+    with jax.named_scope("tick.fmmr"):
+        # ---- 2. FMMR update ------------------------------------------------------
+        now = fmmr.fmmr_now(s_fast.astype(jnp.float32), s_slow.astype(jnp.float32))
+        ewma = fmmr.update_ewma(tenants.a_miss, now, params.ewma_lambda)
+        ewma = jnp.where(tenants.active, ewma, 0.0)
+        tenants = tenants._replace(a_miss=ewma)
 
-    # ---- 4. intra-tenant rebalance (budget R/2; each pair = 2 moves) ---------
-    n_active = jnp.maximum(tenants.active.sum(), 1)
-    rebal_share = (params.migration_budget - realloc_budget) // (2 * n_active)
+    with jax.named_scope("tick.select"):
+        # ---- per-(tenant, tier, clamped count) candidate histograms --------------
+        # ONE P-element scatter; everything below — holdings, candidate totals,
+        # rebalance pair counts, victim cutoffs — reads off these two tables and
+        # their prefix sums.
+        is_owned = pages.owner >= 0
+        owner = jnp.maximum(owner32, 0)
+        slow_cand = is_owned & is_slow
+        fast_cand = is_owned & is_fast
+        if exclude is not None:
+            slow_cand = slow_cand & ~exclude
+            fast_cand = fast_cand & ~exclude
+        key = jnp.minimum(eff.astype(jnp.int32), C - 1)
+        flat = jnp.where(
+            slow_cand,
+            owner * C + key,
+            jnp.where(fast_cand, T * C + owner * C + key, 2 * T * C),
+        )
+        hist2 = jnp.zeros((2 * T * C + 1,), jnp.int32).at[flat].add(1, mode="drop")
+        hist_slow = hist2[: T * C].reshape(T, C)
+        hist_fast = hist2[T * C : 2 * T * C].reshape(T, C)
+        # tiled past 64k-element rows — at [256, 4096] the row scans alone cost
+        # ~20 ms untiled (core/tiling.py; bit-identical integer addition)
+        cum_slow = tiled_cumsum(hist_slow, axis=1)  # [T,C] candidates with count <= c
+        cum_fast = tiled_cumsum(hist_fast, axis=1)
+        n_slow_cand = cum_slow[:, -1]  # == per-tenant slow-page holdings
+        n_fast_cand = cum_fast[:, -1]  # == per-tenant fast-page holdings
+        if exclude is None:
+            fast_hold, slow_hold = n_fast_cand, n_slow_cand
+        else:
+            # in-flight pages are excluded from the candidate histograms but
+            # still occupy their source tier: holdings must count them
+            fast_hold, slow_hold = _per_tenant_pages(
+                pages, max_tenants, segs=segs, owner_onehot=oh
+            )
 
-    # Reallocation consumes the first `give` hottest-slow / `take` coldest-fast
-    # victims; the i-th REBALANCE pair is (hot[give+i], cold[take+i]). Pairs
-    # must fit the remaining candidates on BOTH sides so promote/demote stay
-    # 1:1 per tenant (capacity invariant) — _pair_count enforces this.
-    give_eff = jnp.minimum(ra.give, n_slow_cand)
-    take_eff = jnp.minimum(ra.take, n_fast_cand)
-    n_rebal = _pair_count(cum_slow, cum_fast, give_eff, take_eff, rebal_share)
-    n_rebal = jnp.where(tenants.active, n_rebal, 0)
+    with jax.named_scope("tick.fmmr"):
+        # ---- 3. proportional reallocation (budget R/2) ---------------------------
+        # alloc_headroom fast pages are reserved for first-touch allocation
+        # (DESIGN.md §8): the policy never promotes into them, so a new page's
+        # allocation can land fast instead of waiting an epoch for promotion.
+        # Allocations may transiently consume the reserve (holdings then exceed
+        # the promotion ceiling) — clamp at zero rather than forcing net
+        # demotions; request churn regenerates the headroom on free.
+        free_fast = jnp.maximum(
+            params.fast_capacity - params.alloc_headroom - fast_hold.sum(), 0
+        )
+        realloc_budget = params.migration_budget // 2
+        # asymmetric hysteresis guards: negative band = inherit the symmetric
+        # ``hysteresis`` value, which keeps the default program bit-identical
+        band_need = jnp.where(
+            params.promote_band >= 0, params.promote_band, params.hysteresis
+        )
+        band_donor = jnp.where(
+            params.demote_band >= 0, params.demote_band, params.hysteresis
+        )
+        ra = fmmr.reallocate(
+            tenants, fast_hold, free_fast, realloc_budget,
+            fair_mode=params.fair_mode, hysteresis=params.hysteresis,
+            need_band=band_need, donor_band=band_donor,
+        )
+        tenants = tenants._replace(flagged=ra.flagged)
+        # the R/2 reallocation budget counts BOTH promotions and the demotions
+        # that make room for them: rescale if gives+takes overshoot.
+        ra_moves = ra.give.sum() + ra.take.sum()
+        ra_scale = jnp.where(
+            ra_moves > realloc_budget,
+            realloc_budget.astype(jnp.float32) / jnp.maximum(ra_moves, 1),
+            1.0,
+        )
+        take2 = jnp.floor(ra.take * ra_scale).astype(jnp.int32)
+        give2 = jnp.floor(ra.give * ra_scale).astype(jnp.int32)
+        # integer flooring can break gives <= free + takes: FCFS re-clamp
+        give2 = fmmr.clamp_gives(give2, tenants.arrival, free_fast + take2.sum())
+        ra = ra._replace(give=give2, take=take2)
 
-    # ---- 5. quotas -> victim masks -> plan -----------------------------------
-    promote_quota = give_eff + n_rebal  # <= n_slow_cand by construction
-    demote_quota = take_eff + n_rebal  # <= n_fast_cand by construction
+    with jax.named_scope("tick.select"):
+        # ---- 4. intra-tenant rebalance (budget R/2; each pair = 2 moves) ---------
+        n_active = jnp.maximum(tenants.active.sum(), 1)
+        rebal_share = (params.migration_budget - realloc_budget) // (2 * n_active)
 
-    promote_mask, demote_mask = _select_victims(
-        key, owner, slow_cand, fast_cand, hist_slow, hist_fast,
-        cum_slow, cum_fast, promote_quota, demote_quota, oh, segs,
-    )
+        # Reallocation consumes the first `give` hottest-slow / `take` coldest-fast
+        # victims; the i-th REBALANCE pair is (hot[give+i], cold[take+i]). Pairs
+        # must fit the remaining candidates on BOTH sides so promote/demote stay
+        # 1:1 per tenant (capacity invariant) — _pair_count enforces this.
+        give_eff = jnp.minimum(ra.give, n_slow_cand)
+        take_eff = jnp.minimum(ra.take, n_fast_cand)
+        n_rebal = _pair_count(cum_slow, cum_fast, give_eff, take_eff, rebal_share)
+        n_rebal = jnp.where(tenants.active, n_rebal, 0)
 
-    plan = None
-    if collect_plan:
-        # id lists by rank lookup: the j-th selected page is the first index
-        # whose running selection count reaches j+1 — cumsum + searchsorted
-        # + masked identity, no P-element scatter (XLA:CPU scatters are
-        # element-serial; binary-searching plan_size ranks is ~20x cheaper)
-        j = jnp.arange(plan_size, dtype=jnp.int32)
-        cum_p = tiled_cumsum(promote_mask.astype(jnp.int32))
-        cum_d = tiled_cumsum(demote_mask.astype(jnp.int32))
-        idx_p = jnp.searchsorted(cum_p, j + 1, side="left").astype(jnp.int32)
-        idx_d = jnp.searchsorted(cum_d, j + 1, side="left").astype(jnp.int32)
-        plan = MigrationPlan(
-            promote=jnp.where(j < cum_p[-1], idx_p, -1),
-            demote=jnp.where(j < cum_d[-1], idx_d, -1),
+        # ---- 5. quotas -> victim masks -> plan -----------------------------------
+        promote_quota = give_eff + n_rebal  # <= n_slow_cand by construction
+        demote_quota = take_eff + n_rebal  # <= n_fast_cand by construction
+
+        promote_mask, demote_mask = _select_victims(
+            key, owner, slow_cand, fast_cand, hist_slow, hist_fast,
+            cum_slow, cum_fast, promote_quota, demote_quota, oh, segs,
         )
 
-    # ---- stats ---------------------------------------------------------------
-    # selection takes exactly min(quota, candidates) pages per tenant, so the
-    # per-tenant promoted/demoted telemetry needs no extra reduction.
-    promoted = jnp.minimum(promote_quota, n_slow_cand)
-    demoted = jnp.minimum(demote_quota, n_fast_cand)
-    stats = EpochStats(
-        fmmr_now=now,
-        fmmr_ewma=ewma,
-        fast_pages=fast_hold,
-        slow_pages=slow_hold,
-        promoted=promoted,
-        demoted=demoted,
-        cooled=cooled,
-    )
+        plan = None
+        if collect_plan:
+            # id lists by rank lookup: the j-th selected page is the first index
+            # whose running selection count reaches j+1 — cumsum + searchsorted
+            # + masked identity, no P-element scatter (XLA:CPU scatters are
+            # element-serial; binary-searching plan_size ranks is ~20x cheaper)
+            j = jnp.arange(plan_size, dtype=jnp.int32)
+            cum_p = tiled_cumsum(promote_mask.astype(jnp.int32))
+            cum_d = tiled_cumsum(demote_mask.astype(jnp.int32))
+            idx_p = jnp.searchsorted(cum_p, j + 1, side="left").astype(jnp.int32)
+            idx_d = jnp.searchsorted(cum_d, j + 1, side="left").astype(jnp.int32)
+            plan = MigrationPlan(
+                promote=jnp.where(j < cum_p[-1], idx_p, -1),
+                demote=jnp.where(j < cum_d[-1], idx_d, -1),
+            )
+
+        # ---- stats ---------------------------------------------------------------
+        # selection takes exactly min(quota, candidates) pages per tenant, so the
+        # per-tenant promoted/demoted telemetry needs no extra reduction.
+        promoted = jnp.minimum(promote_quota, n_slow_cand)
+        demoted = jnp.minimum(demote_quota, n_fast_cand)
+        stats = EpochStats(
+            fmmr_now=now,
+            fmmr_ewma=ewma,
+            fast_pages=fast_hold,
+            slow_pages=slow_hold,
+            promoted=promoted,
+            demoted=demoted,
+            cooled=cooled,
+        )
     return pages, tenants, promote_mask, demote_mask, plan, stats
 
 
@@ -859,6 +864,53 @@ def _sentinel_bits(
     return jax.lax.cond(params.sentinel > 0, compute, lambda _: i32(0), None)
 
 
+def _tick(
+    st: PolicyState,
+    pending: jax.Array,  # u32[P] the access backlog this epoch consumes
+    params: PolicyParams,
+    *,
+    max_tenants: int,
+    plan_size: int,
+    exact_sampling: bool,
+    count_clamp: int,
+    collect_plan: bool,
+    compile_sentinel: bool,
+    z: Optional[jax.Array] = None,  # pre-drawn sampling deviates (scan path)
+):
+    """One fused epoch on a ``PolicyState`` (sample -> policy -> commit ->
+    sentinel), the body of ``epoch_step`` and of each ``multi_epoch`` step.
+    Each stage sits in a ``tick.<stage>`` named scope (sample, bins, fmmr,
+    select, queue, sentinel): trace-time metadata that the compiled ops
+    carry, so a device trace can split the tick's time by stage."""
+    with jax.named_scope("tick.sample"):
+        rng, sub = jax.random.split(st.rng)
+        sampled = sample_accesses(
+            sub, pending, params.sample_period, exact=exact_sampling, z=z
+        )
+    with jax.named_scope("tick.queue"):
+        depth_before = None
+        if st.queue is not None and st.queue.size > 0:
+            depth_before = _real_depth(st.queue)
+        exclude = _inflight_mask(st)
+    pages, tenants, pm, dm, plan, stats = _epoch_core(
+        st.pages, st.tenants, sampled, params, max_tenants, plan_size,
+        count_clamp, collect_plan=collect_plan, exclude=exclude, segs=st.segs,
+    )
+    with jax.named_scope("tick.queue"):
+        pages, queue, epoch, stats = _commit(st, pages, tenants, pm, dm, plan, stats, params)
+    if compile_sentinel:
+        with jax.named_scope("tick.sentinel"):
+            stats = stats._replace(sentinel=_sentinel_bits(
+                pages, tenants, params, max_tenants, stats.queue, depth_before
+            ))
+    new_state = st._replace(
+        pages=pages, tenants=tenants,
+        pending=jnp.zeros_like(pending), rng=rng,
+        queue=queue, epoch=epoch,
+    )
+    return new_state, plan, stats
+
+
 def _epoch_step_impl(
     state: PolicyState,
     params: PolicyParams,
@@ -869,27 +921,11 @@ def _epoch_step_impl(
     count_clamp: int,
     compile_sentinel: bool = True,
 ):
-    rng, sub = jax.random.split(state.rng)
-    sampled = sample_accesses(sub, state.pending, params.sample_period, exact=exact_sampling)
-    depth_before = None
-    if state.queue is not None and state.queue.size > 0:
-        depth_before = _real_depth(state.queue)
-    pages, tenants, pm, dm, plan, stats = _epoch_core(
-        state.pages, state.tenants, sampled, params, max_tenants, plan_size,
-        count_clamp, collect_plan=True, exclude=_inflight_mask(state),
-        segs=state.segs,
+    return _tick(
+        state, state.pending, params, max_tenants=max_tenants, plan_size=plan_size,
+        exact_sampling=exact_sampling, count_clamp=count_clamp, collect_plan=True,
+        compile_sentinel=compile_sentinel,
     )
-    pages, queue, epoch, stats = _commit(state, pages, tenants, pm, dm, plan, stats, params)
-    if compile_sentinel:
-        stats = stats._replace(sentinel=_sentinel_bits(
-            pages, tenants, params, max_tenants, stats.queue, depth_before
-        ))
-    new_state = state._replace(
-        pages=pages, tenants=tenants,
-        pending=jnp.zeros_like(state.pending), rng=rng,
-        queue=queue, epoch=epoch,
-    )
-    return new_state, plan, stats
 
 
 @lru_cache(maxsize=None)
@@ -986,13 +1022,14 @@ def _multi_epoch_impl(
     # largest line in the fleet-scan profile (DESIGN.md §5).
     xs_z = None
     if not exact_sampling:
-        half = (P + 1) // 2
-        bits = jax.random.bits(
-            jax.random.fold_in(state.rng, 0x5A), (k, half), jnp.uint32
-        )
-        pc = jax.lax.population_count
-        z2 = jnp.stack([pc(bits & 0xFFFF), pc(bits >> 16)], axis=-1)
-        xs_z = (z2.reshape(k, 2 * half)[:, :P].astype(jnp.float32) - 8.0) * 0.5
+        with jax.named_scope("tick.sample"):
+            half = (P + 1) // 2
+            bits = jax.random.bits(
+                jax.random.fold_in(state.rng, 0x5A), (k, half), jnp.uint32
+            )
+            pc = jax.lax.population_count
+            z2 = jnp.stack([pc(bits & 0xFFFF), pc(bits >> 16)], axis=-1)
+            xs_z = (z2.reshape(k, 2 * half)[:, :P].astype(jnp.float32) - 8.0) * 0.5
 
     # the queue tick consumes the plan id lists, so queue mode always
     # collects them internally even when the caller does not want them out
@@ -1005,29 +1042,15 @@ def _multi_epoch_impl(
             pending = pending + per_epoch
         if x_counts is not None:
             pending = pending + x_counts
-        rng, sub = jax.random.split(st.rng)
-        sampled = sample_accesses(
-            sub, pending, params.sample_period, exact=exact_sampling, z=z
-        )
-        depth_before = _real_depth(st.queue) if queue_mode else None
-        pages, tenants, pm, dm, plan, stats = _epoch_core(
-            st.pages, st.tenants, sampled, params, max_tenants, plan_size,
-            count_clamp, collect_plan=collect_plans or queue_mode,
-            exclude=_inflight_mask(st), segs=st.segs,
-        )
-        pages, queue, epoch, stats = _commit(st, pages, tenants, pm, dm, plan, stats, params)
-        if compile_sentinel:
-            stats = stats._replace(sentinel=_sentinel_bits(
-                pages, tenants, params, max_tenants, stats.queue, depth_before
-            ))
-        st2 = st._replace(
-            pages=pages, tenants=tenants,
-            pending=jnp.zeros_like(pending), rng=rng,
-            queue=queue, epoch=epoch,
+        st2, plan, stats = _tick(
+            st, pending, params, max_tenants=max_tenants, plan_size=plan_size,
+            exact_sampling=exact_sampling, count_clamp=count_clamp,
+            collect_plan=collect_plans or queue_mode,
+            compile_sentinel=compile_sentinel, z=z,
         )
         if trim_stats:
             stats = _trim_stats(stats)
-        return st2, (plan if collect_plans else None, stats, tenants.flagged)
+        return st2, (plan if collect_plans else None, stats, st2.tenants.flagged)
 
     state, (plans, stats, flagged) = jax.lax.scan(step, state, (xs_counts, xs_z), length=k)
     return state, plans, stats, flagged

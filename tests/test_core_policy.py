@@ -219,3 +219,28 @@ class TestPolicyEpoch:
         )
         assert float(tenants.a_miss[0]) < 1e-3
         assert t1_fast > t0_fast  # active tenant captured the fast tier
+
+
+@pytest.mark.parametrize("entry", ["epoch_step", "multi_epoch"])
+def test_tick_stages_are_named_scopes(entry):
+    """Each stage of the fused tick is traced under its ``tick.<stage>``
+    named scope, on the single-step and the scanned path alike: metadata the
+    compiled ops carry into the device trace, read by the benchmark's
+    per-stage metrics."""
+    import re
+
+    from repro.core.manager import CentralManager
+
+    mgr = CentralManager(num_pages=256, fast_capacity=32, migration_budget=16, max_tenants=4,
+                         queue_size=32, migration_bandwidth=16, sample_period=4, sentinel=True)
+    mgr.allocate(mgr.register(0.3), 100)
+    mgr._ensure_segs()
+    kw = dict(max_tenants=4, plan_size=16, exact_sampling=False, count_clamp=policy.COUNT_CLAMP)
+    if entry == "epoch_step":
+        lowered = policy._jitted_epoch_step().lower(mgr._state, mgr.params, **kw)
+    else:
+        lowered = policy._jitted_multi_epoch().lower(mgr._state, mgr.params, None, k=2,
+                                                     collect_plans=False, **kw)
+    scopes = set(re.findall(r"tick\.[a-z]+", lowered.as_text(debug_info=True)))
+    assert scopes == {"tick.sample", "tick.bins", "tick.fmmr", "tick.select", "tick.queue",
+                      "tick.sentinel"}
