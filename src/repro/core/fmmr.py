@@ -26,10 +26,47 @@ from repro.core.types import TenantState
 _EPS = 1e-9
 
 
+def div_rn(x, y) -> jax.Array:
+    """float32 ``x / y`` correctly rounded, in int32 ops.
+
+    A TPU's float32 divide is not correctly rounded: of 1,000,000 quotients
+    the v5e returns about a third one ulp off (PERF.md §4), and a quota
+    floored from such a quotient can land one page off the IEEE result the
+    CPU gives. Here the 24-bit mantissas are divided by shift-and-subtract
+    to 25 quotient bits, and the guard bit rounds (a quotient of two
+    float32 never falls exactly halfway between two of them), so every
+    backend gives the IEEE quotient bit for bit. Zero, non-finite and
+    subnormal operands and results outside the normal range take the plain
+    divide.
+    """
+    x = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(y, jnp.float32)
+    bx = jax.lax.bitcast_convert_type(x, jnp.int32)
+    by = jax.lax.bitcast_convert_type(y, jnp.int32)
+    ex = (bx >> 23) & 0xFF
+    ey = (by >> 23) & 0xFF
+    mx = (bx & 0x7FFFFF) | 0x800000
+    my = (by & 0x7FFFFF) | 0x800000
+    lo = (mx < my).astype(jnp.int32)  # quotient of mantissas below 1
+    r = mx << lo
+    q = jnp.zeros_like(r)
+    for _ in range(25):  # 24 mantissa bits and a guard bit
+        bit = (r >= my).astype(jnp.int32)
+        q = (q << 1) | bit
+        r = (r - bit * my) << 1
+    mant = (q >> 1) + (q & 1)
+    carry = mant >> 24
+    e = ex - ey - lo + 127 + carry
+    bits = ((bx ^ by) & jnp.int32(-(1 << 31))) | (e << 23) | ((mant >> carry) & 0x7FFFFF)
+    exact = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    ok = (ex > 0) & (ex < 255) & (ey > 0) & (ey < 255) & (e > 0) & (e < 255)
+    return jnp.where(ok, exact, x / y)
+
+
 def fmmr_now(a_fast: jax.Array, a_slow: jax.Array) -> jax.Array:
     """Instantaneous FMMR; 0 when no samples (idle tenants decay, §3.1)."""
     tot = a_fast + a_slow
-    return jnp.where(tot > 0, a_slow / jnp.maximum(tot, 1), 0.0).astype(jnp.float32)
+    return jnp.where(tot > 0, div_rn(a_slow, jnp.maximum(tot, 1)), 0.0).astype(jnp.float32)
 
 
 def update_ewma(prev: jax.Array, now: jax.Array, lam) -> jax.Array:
@@ -69,7 +106,7 @@ def reallocate(
 
     # --- takes ---------------------------------------------------------------
     # finite-ratio donors
-    ratio_d = jnp.where(donor_mask & ~zero_donor, t / jnp.maximum(a, _EPS), 0.0)
+    ratio_d = jnp.where(donor_mask & ~zero_donor, div_rn(t, jnp.maximum(a, _EPS)), 0.0)
     # a_miss == 0 donors: ratio would be inf; only the earliest-arrival one
     # donates, and (inf / inf == 1) it absorbs the full take bandwidth.
     any_zero = zero_donor.any()
@@ -79,16 +116,16 @@ def reallocate(
     take_frac = jnp.where(
         any_zero,
         jnp.zeros_like(ratio_d).at[first_zero].set(1.0) * zero_donor.any(),
-        jnp.where(F_surplus > 0, ratio_d / jnp.maximum(F_surplus, _EPS), 0.0),
+        jnp.where(F_surplus > 0, div_rn(ratio_d, jnp.maximum(F_surplus, _EPS)), 0.0),
     )
     take = jnp.minimum(jnp.floor(take_frac * R).astype(jnp.int32), fast_pages)
     take = jnp.where(act, take, 0)
 
     # --- gives ---------------------------------------------------------------
-    ratio_n = jnp.where(need_mask, a / jnp.maximum(t, _EPS), 0.0)
+    ratio_n = jnp.where(need_mask, div_rn(a, jnp.maximum(t, _EPS)), 0.0)
     F_need = ratio_n.sum()
     give_want = jnp.where(
-        F_need > 0, jnp.floor(ratio_n / jnp.maximum(F_need, _EPS) * R), 0.0
+        F_need > 0, jnp.floor(div_rn(ratio_n, jnp.maximum(F_need, _EPS)) * R), 0.0
     ).astype(jnp.int32)
 
     available = free_fast.astype(jnp.int32) + take.sum()
@@ -105,7 +142,7 @@ def reallocate(
     def _fair(give_want):
         scale = jnp.where(
             total_want > 0,
-            jnp.minimum(1.0, available.astype(jnp.float32) / jnp.maximum(total_want, 1)),
+            jnp.minimum(1.0, div_rn(available, jnp.maximum(total_want, 1))),
             0.0,
         )
         return jnp.floor(give_want.astype(jnp.float32) * scale).astype(jnp.int32)
@@ -150,7 +187,7 @@ def reallocate(
 
     def _scale(want, cap):
         tot = jnp.maximum(want.sum(), 1.0)
-        return jnp.floor(want * (jnp.minimum(cap, tot) / tot)).astype(jnp.int32)
+        return jnp.floor(want * div_rn(jnp.minimum(cap, tot), tot)).astype(jnp.int32)
 
     matched = jnp.minimum(
         jnp.minimum(want_take_eq.sum(), want_give_eq.sum() + free_fast), trickle

@@ -41,7 +41,7 @@ from repro.core.faults import (
     SENTINEL_QUEUE,
 )
 from repro.core.sampler import sample_accesses
-from repro.core.tiling import tiled_cumsum
+from repro.core.tiling import CUMSUM_BLOCK, tiled_cumsum
 from repro.core.types import (
     DIR_DEMOTE,
     DIR_NONE,
@@ -72,6 +72,14 @@ from repro.core.types import (
 # dozen O(T*C) passes.
 COUNT_CLAMP = 4096
 
+# Tenant slots at or below this bound take the gather-free in-bucket ranks
+# and holdings (``_bucket_cutoffs``, fused [T, P] compare-and-reduce passes
+# whose cost grows with T * P); above it a state that carries owner segments
+# takes the permutation path (``types.OwnerSegments``: P-long gathers whose
+# cost grows with P alone). A trace-time shape test, like
+# ``tiling.CUMSUM_TILE_THRESHOLD``; set from the chip's crossover (PERF.md).
+GATHER_FREE_MAX_TENANTS = 256
+
 
 def _per_tenant_pages(
     pages: PageState,
@@ -82,8 +90,9 @@ def _per_tenant_pages(
     """(fast_pages[T], slow_pages[T]) holdings.
 
     With owner segments: two O(P) segment cumsums. Otherwise a [T, P]
-    one-hot reduction (still far cheaper than a P-element scatter-add on
-    XLA:CPU, where scatters execute element-serially)."""
+    one-hot reduction, fused into its [T] outputs when no ``owner_onehot``
+    is given (still far cheaper than a P-element scatter-add on XLA:CPU,
+    where scatters execute element-serially)."""
     if segs is not None:
         tier_s = pages.tier[segs.order]
         fast = bins.seg_sums((tier_s == TIER_FAST).astype(jnp.int32), segs.start)
@@ -102,14 +111,11 @@ def _select_victims(
     owner,  # i32[P] owner clamped to >= 0
     slow_cand,  # bool[P] promotion candidates
     fast_cand,  # bool[P] demotion candidates
-    hist_slow,  # i32[T,C]
-    hist_fast,  # i32[T,C]
-    cum_slow,  # i32[T,C] inclusive prefix sums of the histograms
+    cum_slow,  # i32[T,C] inclusive prefix sums of the candidate histograms
     cum_fast,
     pq,  # i32[T] promote quota
     dq,  # i32[T] demote quota
-    owner_onehot,  # bool[T,P] (one-hot path; None when segs is given)
-    segs: Optional[OwnerSegments] = None,
+    segs: Optional[OwnerSegments] = None,  # the permutation path (large T)
 ):
     """(promote_mask, demote_mask) bool[P]: per tenant, exactly the ``pq[t]``
     HOTTEST slow candidates and ``dq[t]`` COLDEST fast candidates.
@@ -117,12 +123,11 @@ def _select_victims(
     Counting-rank selection from the [T, C] candidate histograms: buckets
     strictly beyond a per-tenant cutoff count are taken whole; the single
     bucket each quota lands in is filled in page-id order (stable, matching
-    the seed's lexsort tie-break). The two in-bucket position counters are
-    packed into one u32 prefix sum (promote low 16 bits, demote high 16) —
-    sound for P <= 65536 because the only overflow case (65536 same-count
-    members in one tenant) forces the other side's quota to zero.
+    the seed's lexsort tie-break): up to the page id of its residual-th
+    member (``_bucket_cutoffs``), or, given ``segs``, by in-bucket positions
+    through the owner-sorted permutation (``_occ_segments``).
     """
-    T, C = hist_slow.shape
+    T, C = cum_slow.shape
     P = key.shape[0]
     srch = jax.vmap(partial(jnp.searchsorted, side="left"))
     srch_r = jax.vmap(partial(jnp.searchsorted, side="right"))
@@ -176,96 +181,76 @@ def _select_victims(
 
     if segs is not None:
         occ_p, occ_d = _occ_segments(member_p, member_d, owner, segs)
-    elif P <= 65536:
-        # member counts are bounded by P <= 2^16, and the single possible
-        # wrap (one tenant, all 2^16 pages in one bucket) is healed inside
-        # _occ_packed — no runtime branch needed
-        occ_p, occ_d = _occ_packed(member_p, member_d, owner, owner_onehot)
+        in_p = member_p & (occ_p <= rp_pg)
+        in_d = member_d & (occ_d <= rd_pg)
     else:
-        # a 16-bit field wraps iff one tenant has >= 2^16 members in its
-        # straddling bucket (mid-pool wraps also corrupt the carry, so the
-        # in-packed healing is not enough here); bucket sizes are known, so
-        # branch at runtime — the slow two-pass path only ever executes on
-        # degenerate states
-        safe = jnp.maximum(hist_slow.max(), hist_fast.max()) < (1 << 16)
-        occ_p, occ_d = jax.lax.cond(
-            safe, _occ_packed, _occ_twopass, member_p, member_d, owner, owner_onehot
-        )
+        x_p, x_d = _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T)
+        pid = jnp.arange(P, dtype=jnp.int32)
+        in_p = member_p & (pid <= x_p[owner])
+        in_d = member_d & (pid <= x_d[owner])
 
-    promote = (slow_cand & (key >= cf_pg)) | (member_p & (occ_p <= rp_pg))
-    demote = (fast_cand & (key < nf_pg)) | (member_d & (occ_d <= rd_pg))
+    promote = (slow_cand & (key >= cf_pg)) | in_p
+    demote = (fast_cand & (key < nf_pg)) | in_d
     return promote, demote
+
+
+def _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T: int):
+    """(x_p, x_d) i32[T]: per tenant, the page id of its ``r``-th member in
+    page-id order (P when it has fewer), so ``member & (page id <=
+    x[owner])`` is exactly ``member & (in-bucket position <= r)``.
+
+    No gather reads a P-long source through a P-long index (on the chip
+    each such gather costs about 7 ns an element). Member counts per
+    (tenant, block of ``CUMSUM_BLOCK`` pages) come from ONE fused
+    compare-and-reduce with a [T, nb] output; their running sum over blocks
+    locates the block holding each tenant's r-th member, and a [T, block]
+    slice of that block finds the page inside it. Both member sets ride one
+    u32 per page (promote low 16 bits, demote high 16: a block holds at
+    most 1024 of either). Exact integer counts at any P; the cost grows
+    with T * P, hence ``GATHER_FREE_MAX_TENANTS``.
+    """
+    P = owner.shape[0]
+    B = CUMSUM_BLOCK
+    nb = -(-P // B)
+    packed = member_p.astype(jnp.uint32) | (member_d.astype(jnp.uint32) << 16)
+    packed = jnp.pad(packed, (0, nb * B - P)).reshape(nb, B)
+    own = jnp.pad(owner, (0, nb * B - P)).reshape(nb, B)
+    t = jnp.arange(T, dtype=own.dtype)
+    cnt = jnp.where(own[None] == t[:, None, None], packed[None], 0).sum(
+        axis=2, dtype=jnp.uint32
+    )  # [T, nb]
+
+    def cutoff(r, shift):
+        ccum = jnp.cumsum(((cnt >> shift) & 0xFFFF).astype(jnp.int32), axis=1)
+        # the running sums are non-decreasing: counting the entries below r
+        # is the left-side search for the block of the r-th member
+        b = (ccum < r[:, None]).sum(axis=1)  # nb when the tenant has fewer
+        bi = jnp.minimum(b, nb - 1)
+        before = jnp.take_along_axis(ccum, jnp.maximum(b - 1, 0)[:, None], axis=1)[:, 0]
+        need = r - jnp.where(b > 0, before, 0)
+        row = (own[bi] == t[:, None]) & ((packed[bi] >> shift) & 1 == 1)  # [T, B]
+        j = (jnp.cumsum(row, axis=1, dtype=jnp.int32) < need[:, None]).sum(axis=1)
+        return jnp.where(b < nb, bi * B + j, P).astype(jnp.int32)
+
+    return cutoff(r_p, 0), cutoff(r_d, 16)
 
 
 def _occ_segments(member_p, member_d, owner, segs: OwnerSegments):
     """In-bucket page-id-order positions (1-based) via owner segments:
-    gather the member flags into owner-sorted order, ONE global cumsum,
-    subtract each segment's starting offset, gather back. Within a tenant
-    the sorted order is page-id ascending (stable host sort), so positions
-    are bit-identical to the one-hot [T, P] prefix sum.
-
-    For P <= 65536 both member sets ride one packed u32 cumsum (promote
-    low 16 bits, demote high 16). A field holds the GLOBAL member count at
-    each sorted position; per-segment differences stay below 2^16 except
-    the degenerate all-pages-one-bucket case, where the other side's quota
-    is forced to zero and the wrapped 0 is healed exactly like
-    :func:`_occ_packed`. Beyond 65536 pages the global count itself can
-    wrap mid-pool, so two separate i32 cumsums are used instead.
+    gather the member flags into owner-sorted order, one global cumsum per
+    member set, subtract each segment's starting offset, gather back.
+    Within a tenant the sorted order is page-id ascending (stable host
+    sort), so a member's position is at most r exactly when its page id is
+    at most :func:`_bucket_cutoffs`'s cutoff.
     """
-    P = member_p.shape[0]
     order, inv, start = segs.order, segs.inv, segs.start
-    owner_s = owner[order]
-    if P <= 65536:
-        packed = member_p.astype(jnp.uint32) + (member_d.astype(jnp.uint32) << 16)
-        cum = jnp.cumsum(packed[order])
-        cum0 = jnp.concatenate([jnp.zeros((1,), jnp.uint32), cum])
-        local = (cum - cum0[start[owner_s]])[inv]
-        occ_p = (local & 0xFFFF).astype(jnp.int32)
-        occ_d = (local >> 16).astype(jnp.int32)
-        occ_p = jnp.where(member_p & (occ_p == 0), 1 << 16, occ_p)
-        occ_d = jnp.where(member_d & (occ_d == 0), 1 << 16, occ_d)
-        return occ_p, occ_d
     zero = jnp.zeros((1,), jnp.int32)
     cum_p = tiled_cumsum(member_p[order].astype(jnp.int32))
     cum0_p = jnp.concatenate([zero, cum_p])
     cum_d = tiled_cumsum(member_d[order].astype(jnp.int32))
     cum0_d = jnp.concatenate([zero, cum_d])
-    off = start[owner_s]
+    off = start[owner[order]]
     return (cum_p - cum0_p[off])[inv], (cum_d - cum0_d[off])[inv]
-
-
-def _occ_packed(member_p, member_d, owner, owner_onehot):
-    """In-bucket page-id-order positions (1-based) for both member sets via
-    ONE per-tenant prefix sum: promote occupancy in the low 16 bits, demote
-    in the high 16 (the sets are disjoint, so fields never interact)."""
-    P = member_p.shape[0]
-    packed = member_p.astype(jnp.uint32) + (member_d.astype(jnp.uint32) << 16)
-    cum = jnp.cumsum(
-        jnp.where(owner_onehot, packed[None, :], 0), axis=1, dtype=jnp.uint32
-    )[owner, jnp.arange(P)]
-    occ_p = (cum & 0xFFFF).astype(jnp.int32)
-    occ_d = (cum >> 16).astype(jnp.int32)
-    # members have 1-based positions, so a 0 field can only mean the value
-    # wrapped at exactly 2^16 members (one tenant owning every page of a
-    # 2^16-page pool in one bucket): restore the true position. The +1 the
-    # wrap carries into the high field is unreachable — it would require a
-    # demote member after the last page.
-    occ_p = jnp.where(member_p & (occ_p == 0), 1 << 16, occ_p)
-    occ_d = jnp.where(member_d & (occ_d == 0), 1 << 16, occ_d)
-    return occ_p, occ_d
-
-
-def _occ_twopass(member_p, member_d, owner, owner_onehot):
-    """Wrap-proof fallback: one int32 prefix sum per member set."""
-    P = member_p.shape[0]
-    idx = jnp.arange(P)
-    occ_p = jnp.cumsum(
-        (owner_onehot & member_p[None, :]).astype(jnp.int8), axis=1, dtype=jnp.int32
-    )[owner, idx]
-    occ_d = jnp.cumsum(
-        (owner_onehot & member_d[None, :]).astype(jnp.int8), axis=1, dtype=jnp.int32
-    )[owner, idx]
-    return occ_p, occ_d
 
 
 def _pair_count(cum_slow, cum_fast, give, take, cap):
@@ -405,13 +390,17 @@ def _epoch_core(
         cum_fast = tiled_cumsum(hist_fast, axis=1)
         n_slow_cand = cum_slow[:, -1]  # == per-tenant slow-page holdings
         n_fast_cand = cum_fast[:, -1]  # == per-tenant fast-page holdings
+        # in-bucket ranks and in-flight holdings: through the owner-sorted
+        # permutation only past GATHER_FREE_MAX_TENANTS (its P-long gathers
+        # were the tick's largest cost on the chip), else gather-free
+        perm = segs if T > GATHER_FREE_MAX_TENANTS else None
         if exclude is None:
             fast_hold, slow_hold = n_fast_cand, n_slow_cand
         else:
             # in-flight pages are excluded from the candidate histograms but
             # still occupy their source tier: holdings must count them
             fast_hold, slow_hold = _per_tenant_pages(
-                pages, max_tenants, segs=segs, owner_onehot=oh
+                pages, max_tenants, segs=perm, owner_onehot=oh
             )
 
     with jax.named_scope("tick.fmmr"):
@@ -445,7 +434,7 @@ def _epoch_core(
         ra_moves = ra.give.sum() + ra.take.sum()
         ra_scale = jnp.where(
             ra_moves > realloc_budget,
-            realloc_budget.astype(jnp.float32) / jnp.maximum(ra_moves, 1),
+            fmmr.div_rn(realloc_budget, jnp.maximum(ra_moves, 1)),
             1.0,
         )
         take2 = jnp.floor(ra.take * ra_scale).astype(jnp.int32)
@@ -473,8 +462,8 @@ def _epoch_core(
         demote_quota = take_eff + n_rebal  # <= n_fast_cand by construction
 
         promote_mask, demote_mask = _select_victims(
-            key, owner, slow_cand, fast_cand, hist_slow, hist_fast,
-            cum_slow, cum_fast, promote_quota, demote_quota, oh, segs,
+            key, owner, slow_cand, fast_cand, cum_slow, cum_fast,
+            promote_quota, demote_quota, perm,
         )
 
         plan = None

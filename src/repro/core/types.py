@@ -191,13 +191,17 @@ class OwnerSegments(NamedTuple):
     Page ownership only changes on control-plane operations (allocate /
     free), so the manager keeps a permutation of page ids sorted by
     (owner, page id) — stable, unowned pages last — and rebuilds it there.
-    Inside the fused tick every per-tenant reduction then becomes a gather
-    into owner-sorted order plus ONE global cumsum with per-segment offset
-    subtraction: O(P) gathers/cumsums (cheap, batchable over a fleet axis)
-    instead of [T, P] one-hot passes and P-element scatters (the two op
-    classes XLA:CPU executes serially). Results are bit-identical — the
-    within-tenant order is page-id ascending, exactly the tie-break order
-    the one-hot path reduces in.
+    Its presence selects the tick's scatter-add per-tenant sums and
+    cooling flags (``core/bins.py``) over [T, P] one-hot passes. It is also
+    the large-T path for in-bucket victim ranks and in-flight holdings:
+    past ``policy.GATHER_FREE_MAX_TENANTS`` slots those become a gather
+    into owner-sorted order, ONE global cumsum with per-segment offset
+    subtraction, and a gather back, whose cost grows with P alone. At or
+    below it the tick uses fused [T, P] compare-and-reduce passes instead:
+    on a TPU v5e each of the permutation's P-long gathers costs about 7 ns
+    an element, and eight of them were most of the tick. Results are
+    bit-identical on every path — the within-tenant order is page-id
+    ascending, exactly the tie-break order the other paths rank in.
     """
 
     order: jax.Array  # i32[P] page ids sorted by (owner, id); unowned last

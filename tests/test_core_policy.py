@@ -39,6 +39,30 @@ class TestFMMR:
         out = fmmr.fmmr_now(jnp.array([90.0]), jnp.array([10.0]))
         assert np.isclose(float(out[0]), 0.1)
 
+    @pytest.mark.parametrize("kind", ["bits", "integers", "ratios"])
+    def test_div_rn_is_the_ieee_quotient(self, kind):
+        """``div_rn`` returns numpy's correctly rounded float32 quotient bit
+        for bit wherever operands and result are normal numbers: random bit
+        patterns, integer page counts, and ratios of FMMRs."""
+        rng = np.random.default_rng(len(kind))
+        n = 200_000
+        if kind == "bits":
+            x, y = (rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32).view(np.float32)
+                    for _ in range(2))
+        elif kind == "integers":
+            x = rng.integers(0, 2**24, n).astype(np.float32)
+            y = rng.integers(1, 2**24, n).astype(np.float32)
+        else:
+            x = rng.random(n).astype(np.float32)
+            y = (rng.random(n) + 1e-9).astype(np.float32)
+        with np.errstate(all="ignore"):
+            want = np.divide(x, y)
+        normal = lambda v: np.isfinite(v) & (np.abs(v) >= np.finfo(np.float32).tiny)  # noqa: E731
+        keep = normal(x) & normal(y) & normal(want)
+        got = np.asarray(jax.jit(fmmr.div_rn)(x, y))
+        np.testing.assert_array_equal(got[keep].view(np.uint32), want[keep].view(np.uint32))
+        assert keep.sum() > n // 3
+
     def test_ewma_lambda_half(self):
         out = fmmr.update_ewma(jnp.array([0.4]), jnp.array([0.2]), 0.5)
         assert np.isclose(float(out[0]), 0.3)
@@ -244,3 +268,193 @@ def test_tick_stages_are_named_scopes(entry):
     scopes = set(re.findall(r"tick\.[a-z]+", lowered.as_text(debug_info=True)))
     assert scopes == {"tick.sample", "tick.bins", "tick.fmmr", "tick.select", "tick.queue",
                       "tick.sentinel"}
+
+
+# ------------------------------------------- gather-free ranks and holdings
+# P above the tiled-cumsum threshold and not a multiple of the 1024-page block
+GF_P, GF_T = 131072 + 333, 16
+
+
+def _fresh_epoch_step():
+    """A jit of the tick that traces anew, so the path the module's bound
+    picks at trace time is the one this test forces."""
+    def impl(state, params, *, max_tenants, plan_size, exact_sampling, count_clamp,
+             compile_sentinel=True):
+        return policy._epoch_step_impl(
+            state, params, max_tenants=max_tenants, plan_size=plan_size,
+            exact_sampling=exact_sampling, count_clamp=count_clamp,
+            compile_sentinel=compile_sentinel,
+        )
+
+    return jax.jit(impl, static_argnames=("max_tenants", "plan_size", "exact_sampling",
+                                          "count_clamp", "compile_sentinel"),
+                   donate_argnums=(0,))
+
+
+def _gf_run(case, path, monkeypatch, seen=None):
+    """Six queue-mode epochs of ``case`` on one forced path; tenant B departs
+    after epoch 2 and a new tenant arrives after epoch 3. Returns every
+    epoch's (state less segments, plan, stats) leaves as numpy arrays, and
+    appends each epoch's selection inputs and masks, and the queue's depth
+    going into it (its pages are excluded from selection), to ``seen``."""
+    from repro.core.manager import CentralManager
+
+    step = _fresh_epoch_step()
+    with monkeypatch.context() as mp:
+        mp.setattr(policy, "_jitted_epoch_step", lambda: step)
+        mp.setattr(policy, "GATHER_FREE_MAX_TENANTS", 0 if path == "segments" else GF_T)
+        if seen is not None:
+            select = policy._select_victims
+
+            def recorded(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq, segs):
+                pm, dm = select(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq,
+                                segs)
+                jax.debug.callback(lambda *a: seen.append([np.asarray(v) for v in a]),
+                                   key, owner, slow_cand, fast_cand, pq, dq, pm, dm)
+                return pm, dm
+
+            mp.setattr(policy, "_select_victims", recorded)
+        m = CentralManager(num_pages=GF_P, fast_capacity=16384, max_tenants=GF_T,
+                           migration_budget=case["budget"], queue_size=512,
+                           migration_bandwidth=96, migration_latency=1, sample_period=1,
+                           exact_sampling=True, seed=7)
+        hs = [m.register(t) for t in (0.1, 0.5, 1.0)]
+        for _ in range(3):  # tenants interleaved in runs of 7,000 pages
+            for h in hs:
+                m.allocate(h, 7000)
+        if case["idle_slot"]:
+            m.register(0.3)  # slot 3: active, and never holds a page
+
+        def onehot():
+            if path == "onehot":
+                m._segs_owner = None
+                m._state = m._state._replace(segs=None)
+
+        onehot()
+        rng = np.random.default_rng(11)
+        out = []
+        for e in range(6):
+            owner = np.asarray(m.owners())
+            counts = np.where(owner >= 0, case["base"], 0)
+            # a strided hot set: buckets whose members spread over blocks
+            hot = (owner >= 0) & (np.arange(GF_P) % case["hot_every"] == 0)
+            counts[hot] += rng.integers(case["hot_lo"], case["hot_hi"] + 1, int(hot.sum()))
+            m.record_access(counts)
+            depth = m.queue_depth()
+            r = m.run_epoch()
+            if seen is not None:
+                seen[-1].append(depth)
+            st = m._state._replace(segs=None)
+            out.append([np.asarray(v) for v in jax.tree.leaves((st, r.plan, r.stats))])
+            if e == 2:
+                m.unregister(hs[1])
+                onehot()
+            if e == 3:
+                m.allocate(m.register(0.2), 9000)
+                onehot()
+        return out
+
+
+def _gf_ranked(key, owner, slow_cand, fast_cand, t):
+    """Tenant t's candidates in selection order: slow hottest first, fast
+    coldest first, ties by page id (a lexsort reference)."""
+    s = np.flatnonzero(slow_cand & (owner == t))
+    f = np.flatnonzero(fast_cand & (owner == t))
+    return s[np.lexsort((s, -key[s]))], f[np.lexsort((f, key[f]))]
+
+
+@pytest.mark.parametrize("case", [
+    dict(id="straddle_blocks", budget=2048, base=1, hot_every=3, hot_lo=4, hot_hi=4,
+         idle_slot=False),
+    # a hot set smaller than the rebalance share: promoted whole
+    dict(id="residual_fills_bucket", budget=2048, base=0, hot_every=300, hot_lo=9, hot_hi=9,
+         idle_slot=False),
+    dict(id="zero_quotas", budget=0, base=1, hot_every=3, hot_lo=1, hot_hi=9, idle_slot=False),
+    dict(id="empty_slot", budget=1024, base=0, hot_every=3, hot_lo=1, hot_hi=30,
+         idle_slot=True),
+], ids=lambda c: c["id"])
+def test_gather_free_matches_segments_and_onehot(case, monkeypatch):
+    """At the benchmark's branch (P > 65,536, queue mode with in-flight
+    exclusions, a departure and an arrival mid-run), the gather-free ranks
+    and holdings, the owner-segment permutation and the one-hot path give
+    the same state, plan and stats, leaf for leaf, every epoch; the
+    gather-free selection equals a lexsort reference, and the case's
+    situation occurs in it."""
+    seen = []
+    got = _gf_run(case, "gather_free", monkeypatch, seen)
+    for path in ("segments", "onehot"):
+        want = _gf_run(case, path, monkeypatch)
+        for e, (g, w) in enumerate(zip(got, want)):
+            assert len(g) == len(w)
+            for u, v in zip(g, w):
+                np.testing.assert_array_equal(u, v, err_msg=f"{path}, epoch {e}")
+    assert len(seen) == 6
+    happened = False
+    assert case["budget"] == 0 or max(depth for *_, depth in seen) > 0  # in-flight exclusions
+    for key, owner, slow_cand, fast_cand, pq, dq, pm, dm, _ in seen:
+        for t in range(GF_T):
+            for ranked, q, mask in zip(_gf_ranked(key, owner, slow_cand, fast_cand, t),
+                                       (pq[t], dq[t]), (pm, dm)):
+                q = min(max(int(q), 0), len(ranked))
+                np.testing.assert_array_equal(np.flatnonzero(mask & (owner == t)),
+                                              np.sort(ranked[:q]))
+                if not 0 < q < len(ranked):
+                    continue
+                bucket = ranked[key[ranked] == key[ranked[q - 1]]]
+                taken = np.isin(bucket, ranked[:q])
+                if case["id"] == "straddle_blocks":
+                    # the quota lands inside a bucket whose members span blocks
+                    happened |= (not taken.all()) and len(np.unique(bucket // 1024)) > 1
+                elif case["id"] == "residual_fills_bucket":
+                    # the quota takes its last bucket to the bucket's end
+                    happened |= bool(taken.all()) and (taken.size > 0)
+        if case["id"] == "zero_quotas":
+            happened = True
+            assert (pq == 0).all() and (dq == 0).all()
+        elif case["id"] == "empty_slot":
+            # an active slot that holds no page, beside tenants that do
+            happened |= not (owner == 3).any() and bool((pq + dq).sum() > 0)
+    assert happened, case["id"]
+
+
+def _p_by_p_gathers(hlo: str, P: int):
+    """Gathers in compiled HLO text whose operand and output both hold P
+    elements (a P-long source read through a P-long index)."""
+    import re
+
+    def elems(shape):
+        return int(np.prod([int(d) for d in shape.split(",") if d])) if shape else 1
+
+    shape_of = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    found = []
+    for name, out, operand in re.findall(
+        r"%([\w.-]+) = \w+\[([\d,]*)\]\S* gather\(%([\w.-]+)", hlo
+    ):
+        if elems(out) == P and elems(shape_of.get(operand, "")) == P:
+            found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("T,permutation", [(GF_T, False), (None, True)],
+                         ids=["at_bound", "above_bound"])
+def test_tick_path_chosen_by_tenant_slots(T, permutation):
+    """The compiled tick (queue mode, owner segments on, P > 65,536) reads
+    no P-long source through a P-long index at 16 tenant slots, and keeps
+    the owner-segment permutation's gathers above ``GATHER_FREE_MAX_TENANTS``:
+    the choice is made at trace time, from the shapes alone."""
+    from repro.core.manager import CentralManager
+
+    T = T if T is not None else policy.GATHER_FREE_MAX_TENANTS + 1
+    m = CentralManager(num_pages=GF_P, fast_capacity=16384, migration_budget=64,
+                       max_tenants=T, queue_size=64, migration_bandwidth=32, sample_period=1)
+    m.allocate(m.register(0.5), 1000)
+    m._ensure_segs()
+    hlo = policy._jitted_epoch_step().lower(
+        m._state, m.params, max_tenants=T, plan_size=m.plan_size,
+        exact_sampling=m.exact_sampling, count_clamp=policy.COUNT_CLAMP,
+    ).compile().as_text()
+    gathers = _p_by_p_gathers(hlo, GF_P)
+    if permutation:
+        assert gathers  # into owner-sorted order and back
+    else:
+        assert gathers == []

@@ -86,20 +86,36 @@ class TestExactSelection:
         promoted = sorted(promoted[promoted >= 0].tolist())
         assert promoted == list(range(10, 10 + len(promoted)))
 
-    def test_occ_packed_matches_twopass(self):
-        """The packed 16+16-bit occupancy prefix sum equals the two-pass
-        reference on random member sets."""
-        rng = np.random.default_rng(0)
-        for _ in range(5):
-            P, T = 2048, 5
-            owner = jnp.asarray(rng.integers(0, T, P), jnp.int32)
-            mp = jnp.asarray(rng.random(P) < 0.3)
-            md = jnp.asarray((rng.random(P) < 0.3)) & ~mp  # disjoint sides
-            oh = owner[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]
-            p1, d1 = policy._occ_packed(mp, md, owner, oh)
-            p2, d2 = policy._occ_twopass(mp, md, owner, oh)
-            np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
-            np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+    @pytest.mark.parametrize("P", [700, 2048, 3 * 1024 + 5])
+    def test_bucket_cutoffs_match_rank_reference(self, P):
+        """The r-th member's page id per tenant (P when it has fewer) equals
+        a numpy rank reference, for both member sets: residuals of 0, of
+        the member count and one past it, ending on a block's last member,
+        and random; members spread over several blocks; an empty slot."""
+        rng = np.random.default_rng(P)
+        T = 6  # slot 5 holds no page
+        for _ in range(4):
+            owner = rng.integers(0, T - 1, P)
+            mp = rng.random(P) < 0.3
+            md = (rng.random(P) < 0.3) & ~mp  # disjoint sides
+            rs = []
+            for member in (mp, md):
+                n = [int((member & (owner == t)).sum()) for t in range(T)]
+                first = np.flatnonzero(member & (owner == 2))
+                r = rng.integers(-1, max(n) + 2, T)
+                r[0], r[1], r[3], r[5] = n[0], n[1] + 1, 0, 1
+                r[2] = (first < 1024).sum()  # the first block's last member
+                rs.append(r)
+            x_p, x_d = policy._bucket_cutoffs(
+                jnp.asarray(mp), jnp.asarray(md), jnp.asarray(owner, jnp.int32),
+                jnp.asarray(rs[0], jnp.int32), jnp.asarray(rs[1], jnp.int32), T,
+            )
+            for member, r, x in ((mp, rs[0], x_p), (md, rs[1], x_d)):
+                for t in range(T):
+                    ids = np.flatnonzero(member & (owner == t))
+                    if r[t] >= 1:
+                        want = ids[r[t] - 1] if r[t] <= len(ids) else P
+                        assert int(x[t]) == want, (t, int(r[t]), len(ids))
 
     def test_selection_matches_lexsort_reference(self):
         """Promote/demote sets equal a numpy lexsort reference (exact ranks,
@@ -121,11 +137,10 @@ class TestExactSelection:
 
             hist_slow = bins.count_histogram(key, ownr, slow_cand, C, T)
             hist_fast = bins.count_histogram(key, ownr, fast_cand, C, T)
-            oh = ownr[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]
             pm, dm = policy._select_victims(
-                key, ownr, slow_cand, fast_cand, hist_slow, hist_fast,
+                key, ownr, slow_cand, fast_cand,
                 jnp.cumsum(hist_slow, axis=1), jnp.cumsum(hist_fast, axis=1),
-                jnp.asarray(quota_p, jnp.int32), jnp.asarray(quota_d, jnp.int32), oh,
+                jnp.asarray(quota_p, jnp.int32), jnp.asarray(quota_d, jnp.int32),
             )
             pm, dm = np.asarray(pm), np.asarray(dm)
             for t in range(T):

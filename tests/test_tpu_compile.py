@@ -63,3 +63,28 @@ def test_page_copy_compiles_at_data_plane_width(one_chip):
     pool = jax.ShapeDtypeStruct((DATA_PLANE_ROWS, 128), jnp.float32, sharding=one_chip)
     ids = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
     _compile(page_copy, (staging, pool, ids, ids))
+
+
+def test_policy_tick_compiles_gather_free_at_paper_geometry(one_chip):
+    """The fused policy tick at the benchmark's shape (458,752 pages, 16
+    tenant slots, the 4,096-entry queue, owner segments on) compiles for the
+    v5e with no gather that outputs a page-length array (the owner-segment
+    permutation's, about 7 ns an element on the chip) and no [T, P] buffer
+    outside a fusion: its temporaries stay below one [16, P] bool."""
+    import re
+
+    from repro.core import policy
+    from repro.core.manager import CentralManager
+
+    P, T = 458_752, 16
+    m = CentralManager(num_pages=P, fast_capacity=65_536, migration_budget=2048, max_tenants=T,
+                       sample_period=1, queue_size=4096, migration_bandwidth=2048)
+    m._ensure_segs()
+    shape = lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+                       if hasattr(x, "shape") else x)
+    compiled = policy._jitted_epoch_step().lower(
+        jax.tree.map(shape, m._state), jax.tree.map(shape, m.params), max_tenants=T,
+        plan_size=m.plan_size, exact_sampling=False, count_clamp=policy.COUNT_CLAMP,
+    ).compile()
+    assert not re.search(rf"= \w+\[{P}\]\S* gather\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < T * P
