@@ -22,23 +22,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.tiling import tiled_cumsum
 from repro.core.types import OwnerSegments, PageState, TenantState
-
-
-def seg_sums(values_sorted: jax.Array, start: jax.Array) -> jax.Array:
-    """Per-tenant segment sums of an owner-sorted value array.
-
-    ``values_sorted`` is any [P] array already gathered into owner-sorted
-    order (``x[segs.order]``); ``start`` is ``OwnerSegments.start``. ONE
-    global cumsum (tiled past 64k elements, core/tiling.py) plus two [T+1]
-    gathers replaces a [T, P] one-hot reduction or a P-element scatter-add
-    — bit-identical for integer dtypes (same addends, associative exact
-    arithmetic).
-    """
-    cum = tiled_cumsum(values_sorted)
-    cum0 = jnp.concatenate([jnp.zeros((1,), cum.dtype), cum])
-    return cum0[start[1:]] - cum0[start[:-1]]
 
 
 def bin_of(count: jax.Array, num_bins) -> jax.Array:
@@ -69,7 +53,7 @@ def accumulate_and_count(
     sampled: jax.Array,  # u32[P] sampled accesses this epoch
     num_bins,
     owner_onehot: jax.Array = None,  # bool[T, P] (owner == t), built if None
-    segs: OwnerSegments = None,  # owner segments: cooled via seg_sums instead
+    segs: OwnerSegments = None,  # owner segments: cooled via a [T+1] scatter-add instead
 ) -> Tuple[PageState, TenantState, jax.Array, jax.Array]:
     """Fold one epoch of samples into the counters; fire cooling if needed.
 

@@ -90,7 +90,14 @@ class PagePool:
     # --------------------------------------------------------------- data
     @partial(jax.profiler.annotate_function, name="maxmem.pool.write_pages")
     def write_pages(self, page_ids: Sequence[int], rows: np.ndarray) -> None:
-        """DMA tenant data into page frames (staging -> pool, page_copy)."""
+        """DMA tenant data into page frames (staging -> pool, page_copy).
+
+        At most two staging chunks are on the device at once: each chunk is
+        uploaded while the previous one's copy runs, and the call returns
+        once the last copy has landed. Left unpaced, the uploads of an
+        arrival pile up on the device as far as the host runs ahead of the
+        transfers (six 2 MB chunks at once in one run of the paper's box),
+        so the device's peak memory would depend on timing."""
         ids = np.asarray(page_ids, np.int64)
         rows = np.asarray(rows)
         M = self.plan_slots
@@ -101,10 +108,10 @@ class PagePool:
             src = np.arange(M, dtype=np.int32)
             dst = np.full(M, self.trash, np.int32)
             dst[: len(chunk)] = self.frame[chunk]
-            self.pool = ops.page_copy(
-                jnp.asarray(staging, self.pool.dtype), self.pool,
-                jnp.asarray(src), jnp.asarray(dst),
-            )
+            args = (jnp.asarray(staging, self.pool.dtype), jnp.asarray(src), jnp.asarray(dst))
+            self.pool.block_until_ready()  # the previous chunk's copy, and its staging, are done
+            self.pool = ops.page_copy(args[0], self.pool, args[1], args[2])
+        self.pool.block_until_ready()
 
     def read_page(self, page_id: int) -> np.ndarray:
         f = int(self.frame[page_id])

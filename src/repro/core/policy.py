@@ -72,32 +72,17 @@ from repro.core.types import (
 # dozen O(T*C) passes.
 COUNT_CLAMP = 4096
 
-# Tenant slots at or below this bound take the gather-free in-bucket ranks
-# and holdings (``_bucket_cutoffs``, fused [T, P] compare-and-reduce passes
-# whose cost grows with T * P); above it a state that carries owner segments
-# takes the permutation path (``types.OwnerSegments``: P-long gathers whose
-# cost grows with P alone). A trace-time shape test, like
-# ``tiling.CUMSUM_TILE_THRESHOLD``; set from the chip's crossover (PERF.md).
-GATHER_FREE_MAX_TENANTS = 256
-
 
 def _per_tenant_pages(
     pages: PageState,
     max_tenants: int,
-    segs: Optional[OwnerSegments] = None,
     owner_onehot: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(fast_pages[T], slow_pages[T]) holdings.
-
-    With owner segments: two O(P) segment cumsums. Otherwise a [T, P]
-    one-hot reduction, fused into its [T] outputs when no ``owner_onehot``
-    is given (still far cheaper than a P-element scatter-add on XLA:CPU,
-    where scatters execute element-serially)."""
-    if segs is not None:
-        tier_s = pages.tier[segs.order]
-        fast = bins.seg_sums((tier_s == TIER_FAST).astype(jnp.int32), segs.start)
-        slow = bins.seg_sums((tier_s == TIER_SLOW).astype(jnp.int32), segs.start)
-        return fast, slow
+    """(fast_pages[T], slow_pages[T]) holdings: a [T, P] one-hot reduction,
+    fused into its [T] outputs when no ``owner_onehot`` is given (no
+    P-element scatter-add, which XLA:CPU executes element-serially, and no
+    gather through the owner-sorted permutation, which costs about 7 ns an
+    element on the chip)."""
     if owner_onehot is None:
         T = max_tenants
         owner_onehot = pages.owner[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]
@@ -115,7 +100,6 @@ def _select_victims(
     cum_fast,
     pq,  # i32[T] promote quota
     dq,  # i32[T] demote quota
-    segs: Optional[OwnerSegments] = None,  # the permutation path (large T)
 ):
     """(promote_mask, demote_mask) bool[P]: per tenant, exactly the ``pq[t]``
     HOTTEST slow candidates and ``dq[t]`` COLDEST fast candidates.
@@ -124,8 +108,7 @@ def _select_victims(
     strictly beyond a per-tenant cutoff count are taken whole; the single
     bucket each quota lands in is filled in page-id order (stable, matching
     the seed's lexsort tie-break): up to the page id of its residual-th
-    member (``_bucket_cutoffs``), or, given ``segs``, by in-bucket positions
-    through the owner-sorted permutation (``_occ_segments``).
+    member (``_bucket_cutoffs``).
     """
     T, C = cum_slow.shape
     P = key.shape[0]
@@ -179,15 +162,10 @@ def _select_victims(
     member_p = slow_cand & (key == cf_pg - 1) & (rp_pg > 0)
     member_d = fast_cand & (key == nf_pg) & (rd_pg > 0)
 
-    if segs is not None:
-        occ_p, occ_d = _occ_segments(member_p, member_d, owner, segs)
-        in_p = member_p & (occ_p <= rp_pg)
-        in_d = member_d & (occ_d <= rd_pg)
-    else:
-        x_p, x_d = _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T)
-        pid = jnp.arange(P, dtype=jnp.int32)
-        in_p = member_p & (pid <= x_p[owner])
-        in_d = member_d & (pid <= x_d[owner])
+    x_p, x_d = _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T)
+    pid = jnp.arange(P, dtype=jnp.int32)
+    in_p = member_p & (pid <= x_p[owner])
+    in_d = member_d & (pid <= x_d[owner])
 
     promote = (slow_cand & (key >= cf_pg)) | in_p
     demote = (fast_cand & (key < nf_pg)) | in_d
@@ -207,7 +185,7 @@ def _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T: int):
     slice of that block finds the page inside it. Both member sets ride one
     u32 per page (promote low 16 bits, demote high 16: a block holds at
     most 1024 of either). Exact integer counts at any P; the cost grows
-    with T * P, hence ``GATHER_FREE_MAX_TENANTS``.
+    with T * P.
     """
     P = owner.shape[0]
     B = CUMSUM_BLOCK
@@ -233,24 +211,6 @@ def _bucket_cutoffs(member_p, member_d, owner, r_p, r_d, T: int):
         return jnp.where(b < nb, bi * B + j, P).astype(jnp.int32)
 
     return cutoff(r_p, 0), cutoff(r_d, 16)
-
-
-def _occ_segments(member_p, member_d, owner, segs: OwnerSegments):
-    """In-bucket page-id-order positions (1-based) via owner segments:
-    gather the member flags into owner-sorted order, one global cumsum per
-    member set, subtract each segment's starting offset, gather back.
-    Within a tenant the sorted order is page-id ascending (stable host
-    sort), so a member's position is at most r exactly when its page id is
-    at most :func:`_bucket_cutoffs`'s cutoff.
-    """
-    order, inv, start = segs.order, segs.inv, segs.start
-    zero = jnp.zeros((1,), jnp.int32)
-    cum_p = tiled_cumsum(member_p[order].astype(jnp.int32))
-    cum0_p = jnp.concatenate([zero, cum_p])
-    cum_d = tiled_cumsum(member_d[order].astype(jnp.int32))
-    cum0_d = jnp.concatenate([zero, cum_d])
-    off = start[owner[order]]
-    return (cum_p - cum0_p[off])[inv], (cum_d - cum0_d[off])[inv]
 
 
 def _pair_count(cum_slow, cum_fast, give, take, cap):
@@ -390,18 +350,12 @@ def _epoch_core(
         cum_fast = tiled_cumsum(hist_fast, axis=1)
         n_slow_cand = cum_slow[:, -1]  # == per-tenant slow-page holdings
         n_fast_cand = cum_fast[:, -1]  # == per-tenant fast-page holdings
-        # in-bucket ranks and in-flight holdings: through the owner-sorted
-        # permutation only past GATHER_FREE_MAX_TENANTS (its P-long gathers
-        # were the tick's largest cost on the chip), else gather-free
-        perm = segs if T > GATHER_FREE_MAX_TENANTS else None
         if exclude is None:
             fast_hold, slow_hold = n_fast_cand, n_slow_cand
         else:
             # in-flight pages are excluded from the candidate histograms but
             # still occupy their source tier: holdings must count them
-            fast_hold, slow_hold = _per_tenant_pages(
-                pages, max_tenants, segs=perm, owner_onehot=oh
-            )
+            fast_hold, slow_hold = _per_tenant_pages(pages, max_tenants, owner_onehot=oh)
 
     with jax.named_scope("tick.fmmr"):
         # ---- 3. proportional reallocation (budget R/2) ---------------------------
@@ -463,7 +417,7 @@ def _epoch_core(
 
         promote_mask, demote_mask = _select_victims(
             key, owner, slow_cand, fast_cand, cum_slow, cum_fast,
-            promote_quota, demote_quota, perm,
+            promote_quota, demote_quota,
         )
 
         plan = None

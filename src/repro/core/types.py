@@ -192,16 +192,14 @@ class OwnerSegments(NamedTuple):
     free), so the manager keeps a permutation of page ids sorted by
     (owner, page id) — stable, unowned pages last — and rebuilds it there.
     Its presence selects the tick's scatter-add per-tenant sums and
-    cooling flags (``core/bins.py``) over [T, P] one-hot passes. It is also
-    the large-T path for in-bucket victim ranks and in-flight holdings:
-    past ``policy.GATHER_FREE_MAX_TENANTS`` slots those become a gather
-    into owner-sorted order, ONE global cumsum with per-segment offset
-    subtraction, and a gather back, whose cost grows with P alone. At or
-    below it the tick uses fused [T, P] compare-and-reduce passes instead:
-    on a TPU v5e each of the permutation's P-long gathers costs about 7 ns
-    an element, and eight of them were most of the tick. Results are
-    bit-identical on every path — the within-tenant order is page-id
-    ascending, exactly the tie-break order the other paths rank in.
+    cooling flags (``core/bins.py``) over [T, P] one-hot passes; the tick
+    reads none of its arrays. In-bucket victim ranks and in-flight holdings
+    once went through the permutation (a gather into owner-sorted order,
+    a global cumsum, a gather back); on a TPU v5e each of those P-long
+    gathers costs about 7 ns an element, and eight of them were most of
+    the tick, so they are fused [T, P] compare-and-reduce passes at every
+    T now (``policy._bucket_cutoffs``). Results are bit-identical on both
+    paths.
     """
 
     order: jax.Array  # i32[P] page ids sorted by (owner, id); unowned last

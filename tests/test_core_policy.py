@@ -276,8 +276,8 @@ GF_P, GF_T = 131072 + 333, 16
 
 
 def _fresh_epoch_step():
-    """A jit of the tick that traces anew, so the path the module's bound
-    picks at trace time is the one this test forces."""
+    """A jit of the tick that traces anew, so a ``_select_victims`` patched
+    in by the test is the one it runs."""
     def impl(state, params, *, max_tenants, plan_size, exact_sampling, count_clamp,
              compile_sentinel=True):
         return policy._epoch_step_impl(
@@ -292,7 +292,8 @@ def _fresh_epoch_step():
 
 
 def _gf_run(case, path, monkeypatch, seen=None):
-    """Six queue-mode epochs of ``case`` on one forced path; tenant B departs
+    """Six queue-mode epochs of ``case`` on one path (``"segments"``: the
+    manager's state with owner segments; ``"onehot"``: without); tenant B departs
     after epoch 2 and a new tenant arrives after epoch 3. Returns every
     epoch's (state less segments, plan, stats) leaves as numpy arrays, and
     appends each epoch's selection inputs and masks, and the queue's depth
@@ -302,13 +303,11 @@ def _gf_run(case, path, monkeypatch, seen=None):
     step = _fresh_epoch_step()
     with monkeypatch.context() as mp:
         mp.setattr(policy, "_jitted_epoch_step", lambda: step)
-        mp.setattr(policy, "GATHER_FREE_MAX_TENANTS", 0 if path == "segments" else GF_T)
         if seen is not None:
             select = policy._select_victims
 
-            def recorded(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq, segs):
-                pm, dm = select(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq,
-                                segs)
+            def recorded(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq):
+                pm, dm = select(key, owner, slow_cand, fast_cand, cum_slow, cum_fast, pq, dq)
                 jax.debug.callback(lambda *a: seen.append([np.asarray(v) for v in a]),
                                    key, owner, slow_cand, fast_cand, pq, dq, pm, dm)
                 return pm, dm
@@ -376,18 +375,17 @@ def _gf_ranked(key, owner, slow_cand, fast_cand, t):
 def test_gather_free_matches_segments_and_onehot(case, monkeypatch):
     """At the benchmark's branch (P > 65,536, queue mode with in-flight
     exclusions, a departure and an arrival mid-run), the gather-free ranks
-    and holdings, the owner-segment permutation and the one-hot path give
-    the same state, plan and stats, leaf for leaf, every epoch; the
-    gather-free selection equals a lexsort reference, and the case's
-    situation occurs in it."""
+    and holdings give the same state, plan and stats, leaf for leaf, every
+    epoch, whether the state carries owner segments (scatter-add bins) or
+    not (one-hot bins); the selection equals a lexsort reference, and the
+    case's situation occurs in it."""
     seen = []
-    got = _gf_run(case, "gather_free", monkeypatch, seen)
-    for path in ("segments", "onehot"):
-        want = _gf_run(case, path, monkeypatch)
-        for e, (g, w) in enumerate(zip(got, want)):
-            assert len(g) == len(w)
-            for u, v in zip(g, w):
-                np.testing.assert_array_equal(u, v, err_msg=f"{path}, epoch {e}")
+    got = _gf_run(case, "segments", monkeypatch, seen)
+    want = _gf_run(case, "onehot", monkeypatch)
+    for e, (g, w) in enumerate(zip(got, want)):
+        assert len(g) == len(w)
+        for u, v in zip(g, w):
+            np.testing.assert_array_equal(u, v, err_msg=f"epoch {e}")
     assert len(seen) == 6
     happened = False
     assert case["budget"] == 0 or max(depth for *_, depth in seen) > 0  # in-flight exclusions
@@ -435,16 +433,14 @@ def _p_by_p_gathers(hlo: str, P: int):
     return found
 
 
-@pytest.mark.parametrize("T,permutation", [(GF_T, False), (None, True)],
-                         ids=["at_bound", "above_bound"])
-def test_tick_path_chosen_by_tenant_slots(T, permutation):
+@pytest.mark.parametrize("T", [GF_T, 256])
+def test_tick_reads_no_page_by_page_gather(T):
     """The compiled tick (queue mode, owner segments on, P > 65,536) reads
-    no P-long source through a P-long index at 16 tenant slots, and keeps
-    the owner-segment permutation's gathers above ``GATHER_FREE_MAX_TENANTS``:
-    the choice is made at trace time, from the shapes alone."""
+    no P-long source through a P-long index, at the benchmark's 16 tenant
+    slots and at the 256 of the largest scale geometry: in-bucket ranks and
+    holdings take the gather-free path at every T."""
     from repro.core.manager import CentralManager
 
-    T = T if T is not None else policy.GATHER_FREE_MAX_TENANTS + 1
     m = CentralManager(num_pages=GF_P, fast_capacity=16384, migration_budget=64,
                        max_tenants=T, queue_size=64, migration_bandwidth=32, sample_period=1)
     m.allocate(m.register(0.5), 1000)
@@ -453,8 +449,4 @@ def test_tick_path_chosen_by_tenant_slots(T, permutation):
         m._state, m.params, max_tenants=T, plan_size=m.plan_size,
         exact_sampling=m.exact_sampling, count_clamp=policy.COUNT_CLAMP,
     ).compile().as_text()
-    gathers = _p_by_p_gathers(hlo, GF_P)
-    if permutation:
-        assert gathers  # into owner-sorted order and back
-    else:
-        assert gathers == []
+    assert _p_by_p_gathers(hlo, GF_P) == []
