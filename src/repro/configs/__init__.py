@@ -20,7 +20,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "qwen2.5-32b": "repro.configs.qwen2_5_32b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "chameleon-34b": "repro.configs.chameleon_34b",
-    "moonshot-v1-16b-a3b": "repro.configs.moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "qwen2-moe-a2.7b": "repro.configs.qwen2_moe_a2_7b",
     "mamba2-130m": "repro.configs.mamba2_130m",
     "whisper-tiny": "repro.configs.whisper_tiny",

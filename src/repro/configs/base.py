@@ -48,6 +48,26 @@ class ModelConfig:
     # pad expert weight arrays to this count for even EP sharding (0 = none);
     # routing stays over the REAL num_experts (dead pad experts never hit)
     expert_pad_to: int = 0
+    # DeepSeek-V3 routing (moe.py keeps softmax top-k; models/deepseek.py
+    # reads these): sigmoid scores, top-k of score + correction bias
+    scoring_func: str = "softmax"  # softmax | sigmoid
+    topk_method: str = "greedy"  # greedy | noaux_tc
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0  # leading dense layers before the MoE ones
+    dense_d_ff: int = 0  # SwiGLU width of those dense layers
+    # the chip's share of an expert-parallel deployment: this chip holds
+    # experts [expert_rank * experts_held, +experts_held) of every MoE layer
+    # and routes over all num_experts (0 = all experts held)
+    experts_held: int = 0
+    expert_rank: int = 0
+
+    # -- multi-head latent attention (MLA; kv_lora_rank > 0) -------------------
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0  # 0: q projected directly (the published null)
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # -- SSM (Mamba2 / SSD) --------------------------------------------------
     ssm_state: int = 0
@@ -85,6 +105,19 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one token's cached MLA latent: c_kv plus the shared k_rope."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def is_ssm(self) -> bool:
         return self.family == "ssm"
 
@@ -119,6 +152,8 @@ class ModelConfig:
         """Analytic parameter count (embedding + blocks + head)."""
         d, dh = self.d_model, self.d_head
         n = 0
+        if self.is_mla:
+            return self._mla_param_count(self.num_experts)
         n += self.vocab_size * d  # embed
         if not self.tie_embeddings:
             n += self.vocab_size * d  # lm head
@@ -138,10 +173,37 @@ class ModelConfig:
         n += d  # final norm
         return n
 
+    def _mla_param_count(self, experts: int) -> int:
+        """MLA + DeepSeek-V3 MoE stack with ``experts`` routed experts a layer."""
+        d, nh = self.d_model, self.num_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        attn = (d * nh * qk + d * self.latent_dim + self.kv_lora_rank
+                + self.kv_lora_rank * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                + nh * self.v_head_dim * d)
+        expert = 3 * d * self.moe_d_ff
+        moe = ((experts + self.num_shared_experts) * expert
+               + d * self.num_experts + self.num_experts)  # router + correction bias
+        k0 = self.first_k_dense_replace
+        n = self.vocab_size * d * (1 if self.tie_embeddings else 2) + d
+        n += self.num_layers * (attn + 2 * d)
+        n += k0 * 3 * d * self.dense_d_ff + (self.num_layers - k0) * moe
+        return n
+
+    def held_param_count(self) -> int:
+        """Parameters one chip holds: every layer, ``held_experts`` of each
+        MoE layer's routed experts."""
+        if not self.is_mla:
+            return self.param_count()
+        return self._mla_param_count(self.held_experts)
+
     def active_param_count(self) -> int:
         """Per-token active parameters (differs from total for MoE)."""
         if not self.is_moe:
             return self.param_count()
+        if self.is_mla:
+            k0 = self.first_k_dense_replace
+            routed = (self.num_experts - self.moe_top_k) * 3 * self.d_model * self.moe_d_ff
+            return self.param_count() - (self.num_layers - k0) * routed
         d = self.d_model
         dense = self.param_count() - self.num_layers * self._mlp_params()
         act_mlp = (self.moe_top_k + self.num_shared_experts) * 3 * d * self.moe_d_ff
@@ -181,6 +243,14 @@ class ModelConfig:
     # ------------------------------------------------------------------ smoke
     def smoke(self) -> "ModelConfig":
         """Reduced config of the same family for CPU smoke tests."""
+        if self.is_mla:  # every MLA / router mechanism, at toy widths
+            return dataclasses.replace(
+                self, name=self.name + "-smoke", num_layers=3, d_model=64, num_heads=4,
+                num_kv_heads=4, d_head=24, d_ff=96, dense_d_ff=96, vocab_size=256,
+                moe_d_ff=32, num_experts=16, moe_top_k=4, num_shared_experts=2,
+                experts_held=0, expert_rank=0, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16,
+                param_dtype="float32", compute_dtype="float32")
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",

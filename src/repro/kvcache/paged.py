@@ -5,25 +5,35 @@ A *logical page* (what MaxMem tracks and migrates) is a block of
 both K and V — for yi-6b with 16-token pages that is ~0.5 MB, i.e. exactly a
 huge-page-sized migration unit (DESIGN.md §2).
 
-Physically, pools are [L, n_slots, page, nkv, dh] for K and V, in the
-model's compute dtype. Slots [0, n_fast) are the fast tier and slots
-[n_fast, n_slots) the slow tier; today both are slot ranges of one HBM
-array, and a slow tier in host memory is future work. ``slot_of`` maps
-logical page id -> physical slot; migration copies slot contents across the
-boundary and rewrites the mapping — block tables hold logical ids and never
-change.
+Physically a cache is a tuple of ``[L, n_slots, *row]`` pools:
 
-Page heat summaries (Quest-style per-page key min/max) ride along for the
-top-k page selector in the serving engine.
+* grouped-query attention keeps ``(k, v)``, each row ``[page, nkv, dh]`` in
+  the compute dtype, plus the Quest summaries ``(kmax, kmin)``, per-page
+  key max/min rows ``[nkv, dh]`` in float32 for the top-k page selector;
+* multi-head latent attention (``cfg.is_mla``) keeps one latent pool, rows
+  ``[page, latent_dim]``: each token's ``c_kv || k_rope``, shared by every
+  head, and no summaries (its decode attends to every page exactly). Each
+  latent is padded with zeros to whole 128-lane tiles (576 -> 640), the
+  row the TPU lays out without moving the slot axis (``lane_width``).
+
+Slots [0, n_fast) are the fast tier and slots [n_fast, n_slots) the slow
+tier; today both are slot ranges of one HBM array, and a slow tier in host
+memory is future work. ``slot_of`` maps logical page id -> physical slot;
+migration copies slot contents across the boundary in every pool and
+rewrites the mapping — block tables hold logical ids and never change.
 
 Free/reuse invariant (DESIGN.md §8): the slot of an unallocated logical page
-always holds zeroed K/V content and reset (±inf) Quest summaries. Two paths
+always holds zeroed content and reset (±inf) Quest summaries. Two paths
 maintain it: :meth:`TieredPagedKV.free_pages` scrubs slots when a sequence
 finishes, and :meth:`TieredPagedKV.migrate` re-scrubs the vacated source
 rows its swaps hand to free holders (``page_move`` has gather semantics, so
 a swapped-out row otherwise retains a stale copy of the migrated page).
 Without the invariant, a reused page's ``write_tokens`` folds max/min
 against the PREVIOUS owner's summaries, corrupting Quest top-k selection.
+
+Writes, scrubs and moves are each one jitted device update over every pool
+(the pools donated), with index lists padded to a power of two so a few
+compiled programs serve every size; :meth:`TieredPagedKV.warm` compiles them.
 """
 from __future__ import annotations
 
@@ -54,6 +64,70 @@ def _move_slots(pools, src, dst):
     return tuple(out)
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def lane_width(n: int) -> int:
+    """A row of ``n`` elements padded to the TPU's 128-lane tile. A pool
+    whose minor dimension is not a multiple of it is laid out on the device
+    with the slots innermost, and every gather or scatter by slot then
+    copies the whole pool."""
+    return -(-n // 128) * 128
+
+
+def put_rows(pool, slots, rows, op: str = "set"):
+    """``pool[l, slots[t]] <op>= rows[l, t]`` for every layer, in place: one
+    scatter over the pool's ``[L * n_slots, ...]`` row view (a scatter into
+    the 4-D or 5-D pool by two index arrays is not done in place on the
+    TPU). Out-of-range slots are dropped; rows narrower than the pool's last
+    dimension are padded with zeros."""
+    L, n = pool.shape[:2]
+    idx = jnp.arange(L, dtype=jnp.int32)[:, None] * n + slots[None, :]
+    idx = jnp.where((slots < n)[None, :], idx, L * n).reshape(-1)
+    rows = rows.astype(pool.dtype)
+    pad = pool.shape[-1] - rows.shape[-1]
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+    flat = pool.reshape(L * n, *pool.shape[2:])
+    at = flat.at[idx]
+    vals = rows.reshape(idx.shape[0], *flat.shape[1:])
+    out = {"set": at.set, "max": at.max, "min": at.min}[op](vals, mode="drop")
+    return out.reshape(pool.shape)
+
+
+def put_tokens(pool, slots, offs, rows):
+    """Write token ``t``'s row ``rows[l, t]`` at page offset ``offs[t]`` of
+    slot ``slots[t]`` in every layer of a ``[L, n_slots, page, ...]`` pool."""
+    L, n, page = pool.shape[:3]
+    flat = pool.reshape(L, n * page, *pool.shape[3:])
+    tok = jnp.where(slots < n, slots * page + offs, n * page)
+    return put_rows(flat, tok, rows).reshape(pool.shape)
+
+
+@partial(jax.jit, static_argnames=("n_content",), donate_argnums=(0,))
+def _scatter_tokens(pools, rows, slots, offs, n_content: int):
+    """Write token rows into pages: ``rows[i]`` is ``[L, T, *tail]`` for
+    content pool ``i``; token ``t`` goes to (``slots[t]``, ``offs[t]``), and a
+    slot out of range drops it. Summary pools after the content ones fold
+    the first pool's rows (keys) in by max and min."""
+    with jax.named_scope("mla.latent_write"):
+        out = [put_tokens(p, slots, offs, r) for p, r in zip(pools[:n_content], rows)]
+    if len(pools) > n_content:
+        k = rows[0].astype(jnp.float32)
+        out.append(put_rows(pools[n_content], slots, k, "max"))
+        out.append(put_rows(pools[n_content + 1], slots, k, "min"))
+    return tuple(out)
+
+
+@partial(jax.jit, static_argnames=("values",), donate_argnums=(0,))
+def _scrub(pools, slots, values):
+    """Reset the given slots of every pool to its free value (out-of-range
+    slots, the padding, are dropped)."""
+    return tuple(put_rows(p, slots, jnp.full((p.shape[0], slots.shape[0], *p.shape[2:]), v, p.dtype))
+                 for p, v in zip(pools, values))
+
+
 class TieredPagedKV:
     def __init__(
         self,
@@ -61,73 +135,83 @@ class TieredPagedKV:
         n_fast_slots: int,
         n_slow_slots: int,
         page_tokens: int = 16,
+        dtype=None,
     ):
+        """``dtype`` stores the content pools in another dtype than the
+        model's compute dtype (a lower-precision control); decode computes
+        in the compute dtype either way."""
         self.cfg = cfg
         self.page = page_tokens
         self.n_fast = n_fast_slots
         self.n_slots = n_fast_slots + n_slow_slots
-        L, nkv, dh = cfg.num_layers, cfg.num_kv_heads, cfg.d_head
-        self.k_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), cfg.cdtype)
-        self.v_pool = jnp.zeros((L, self.n_slots, page_tokens, nkv, dh), cfg.cdtype)
-        # Quest summaries (per layer): elementwise min/max of keys in the page
-        self.k_max = jnp.full((L, self.n_slots, nkv, dh), -jnp.inf, jnp.float32)
-        self.k_min = jnp.full((L, self.n_slots, nkv, dh), jnp.inf, jnp.float32)
+        L, n, dt = cfg.num_layers, self.n_slots, dtype or cfg.cdtype
+        if cfg.is_mla:  # latents padded to whole lane tiles; the pad stays zero
+            self.pools = (jnp.zeros((L, n, page_tokens, lane_width(cfg.latent_dim)), dt),)
+            self._free = (0.0,)
+        else:
+            nkv, dh = cfg.num_kv_heads, cfg.d_head
+            self.pools = (
+                jnp.zeros((L, n, page_tokens, nkv, dh), dt),
+                jnp.zeros((L, n, page_tokens, nkv, dh), dt),
+                # Quest summaries (per layer): elementwise min/max of keys in the page
+                jnp.full((L, n, nkv, dh), -jnp.inf, jnp.float32),
+                jnp.full((L, n, nkv, dh), jnp.inf, jnp.float32),
+            )
+            self._free = (0.0, 0.0, -float("inf"), float("inf"))
+        self.n_content = 1 if cfg.is_mla else 2
         # logical page id -> physical slot. Identity at boot: manager hands
         # out page ids with tier semantics (id < n_fast iff fast at alloc).
         self.slot_of = np.arange(self.n_slots, dtype=np.int32)
-        self._slot_owner = np.full(self.n_slots, -1, np.int32)  # logical page or -1
+
+    # the grouped-query pools by name
+    k_pool = property(lambda self: self.pools[0])
+    v_pool = property(lambda self: self.pools[1])
+    k_max = property(lambda self: self.pools[2])
+    k_min = property(lambda self: self.pools[3])
 
     # ------------------------------------------------------------ mapping
     def slots_for(self, logical_pages: np.ndarray) -> np.ndarray:
         return self.slot_of[np.asarray(logical_pages)]
 
     def page_bytes(self) -> int:
-        L, nkv, dh = self.cfg.num_layers, self.cfg.num_kv_heads, self.cfg.d_head
-        return L * 2 * self.page * nkv * dh * self.k_pool.dtype.itemsize
+        """Bytes of one logical page's content (summaries not counted)."""
+        return sum(int(np.prod(p.shape[2:])) * p.dtype.itemsize * p.shape[0]
+                   for p in self.pools[: self.n_content])
 
     # ------------------------------------------------------------ writes
     def write_tokens(
         self,
-        layer_kv: Tuple[jax.Array, jax.Array],  # k,v: [L, B, T, nkv, dh]
+        rows,  # per content pool [L, B, T, *tail]: (k, v) or (latent,)
         logical_pages: np.ndarray,  # [B, n_pages_of_write] logical ids
-        start_pos: int,
+        start_pos: int = 0,
+        length: int | None = None,
     ) -> None:
-        """Scatter T tokens (from prefill) into pages. Host-side loop over
-        pages — prefill writes are not the steady-state hot path."""
-        k, v = layer_kv
-        L, B, T, nkv, dh = k.shape
+        """Scatter the first ``length`` (default all T) tokens of each
+        sequence, from position ``start_pos`` on, into their pages: one
+        device update for every pool. Tokens past ``length`` (a padded
+        prompt's tail) are not written."""
+        L, B, T = rows[0].shape[:3]
         p = self.page
-        for b in range(B):
-            for j in range((start_pos + T + p - 1) // p):
-                lo = max(j * p - start_pos, 0)
-                hi = min((j + 1) * p - start_pos, T)
-                if hi <= lo:
-                    continue
-                slot = int(self.slot_of[int(logical_pages[b, j])])
-                off = (start_pos + lo) % p
-                kb = k[:, b, lo:hi]
-                vb = v[:, b, lo:hi]
-                self.k_pool = jax.lax.dynamic_update_slice(
-                    self.k_pool, kb[:, None].astype(self.k_pool.dtype), (0, slot, off, 0, 0)
-                )
-                self.v_pool = jax.lax.dynamic_update_slice(
-                    self.v_pool, vb[:, None].astype(self.v_pool.dtype), (0, slot, off, 0, 0)
-                )
-                kmax = jnp.maximum(self.k_max[:, slot], kb.max(axis=1).astype(jnp.float32))
-                kmin = jnp.minimum(self.k_min[:, slot], kb.min(axis=1).astype(jnp.float32))
-                self.k_max = self.k_max.at[:, slot].set(kmax)
-                self.k_min = self.k_min.at[:, slot].set(kmin)
+        t = np.arange(T)
+        pos = start_pos + t
+        tables = np.asarray(logical_pages).reshape(B, -1)
+        pages = tables[:, np.minimum(pos // p, tables.shape[1] - 1)]  # [B, T]
+        keep = (t < (T if length is None else length))[None, :] & (pages >= 0)
+        slots = np.where(keep, self.slot_of[np.maximum(pages, 0)], self.n_slots)
+        offs = np.broadcast_to(pos % p, (B, T))
+        flat = tuple(r.reshape(L, B * T, *r.shape[3:]) for r in rows)
+        self.pools = _scatter_tokens(
+            self.pools, flat, jnp.asarray(slots.reshape(-1).astype(np.int32)),
+            jnp.asarray(offs.reshape(-1).astype(np.int32)), n_content=self.n_content)
 
     def _scrub_slots(self, slots: np.ndarray) -> None:
-        """Reset the given physical slots to the free-slot state: zero K/V
-        content, ±inf Quest summaries (one fused device update per pool)."""
+        """Reset the given physical slots to the free-slot state: zero
+        content, ±inf Quest summaries (one device update for every pool)."""
         if len(slots) == 0:
             return
-        s = jnp.asarray(np.asarray(slots, np.int32))
-        self.k_pool = self.k_pool.at[:, s].set(0)
-        self.v_pool = self.v_pool.at[:, s].set(0)
-        self.k_max = self.k_max.at[:, s].set(-jnp.inf)
-        self.k_min = self.k_min.at[:, s].set(jnp.inf)
+        s = np.full(_pow2(len(slots)), self.n_slots, np.int32)
+        s[: len(slots)] = slots
+        self.pools = _scrub(self.pools, jnp.asarray(s), self._free)
 
     def free_pages(self, logical_pages) -> None:
         """Scrub the slots of freed logical pages (call BEFORE or after the
@@ -142,6 +226,19 @@ class TieredPagedKV:
         if ids.size == 0:
             return
         self._scrub_slots(self.slot_of[ids])
+
+    def warm(self, max_pages: int) -> None:
+        """Compile the scrub and move programs for every padded size up to
+        ``max_pages`` pages, without changing any slot's contents."""
+        n = 1
+        while True:
+            self.pools = _scrub(self.pools, jnp.full(n, self.n_slots, jnp.int32), self._free)
+            same = jnp.zeros(n, jnp.int32)  # slot 0 onto itself
+            self.pools = _move_slots(self.pools, same, same)
+            if n >= max_pages:
+                break
+            n *= 2
+        jax.block_until_ready(self.pools)
 
     # ------------------------------------------------------------ migration
     def apply_drained(self, promote_ids, demote_ids, manager: CentralManager) -> int:
@@ -174,8 +271,9 @@ class TieredPagedKV:
         owner = np.asarray(manager.pages.owner)
         inv = np.empty_like(self.slot_of)
         inv[self.slot_of] = np.arange(self.n_slots, dtype=np.int32)
-        free_fast = [s for s in range(self.n_fast) if owner[inv[s]] < 0]
-        free_slow = [s for s in range(self.n_fast, self.n_slots) if owner[inv[s]] < 0]
+        free = np.flatnonzero(owner[inv] < 0)
+        free_fast = free[free < self.n_fast].tolist()
+        free_slow = free[free >= self.n_fast].tolist()
 
         moves_src: List[int] = []
         moves_dst: List[int] = []
@@ -211,12 +309,11 @@ class TieredPagedKV:
         # pad to a power of two so plan sizes reuse a few compiled programs;
         # the pad repeats move 0, whose duplicate gathers read the same
         # pre-plan row and write the same bytes
-        m = len(moves_src)
-        pad = (1 << (m - 1).bit_length()) - m
-        self.k_pool, self.v_pool, self.k_max, self.k_min = _move_slots(
-            (self.k_pool, self.v_pool, self.k_max, self.k_min),
-            jnp.asarray(moves_src + moves_src[:1] * pad, jnp.int32),
-            jnp.asarray(moves_dst + moves_dst[:1] * pad, jnp.int32),
+        pad = _pow2(len(moves_src)) - len(moves_src)
+        self.pools = _move_slots(
+            self.pools,
+            jnp.asarray(np.asarray(moves_src + moves_src[:1] * pad, np.int32)),
+            jnp.asarray(np.asarray(moves_dst + moves_dst[:1] * pad, np.int32)),
         )
         # page_move is a gather: a swapped-out source row keeps a stale COPY
         # of the migrated page's data. Any such row now held by a free
@@ -232,10 +329,11 @@ class TieredPagedKV:
     def tier_of_pages(self, logical_pages: np.ndarray) -> np.ndarray:
         return np.where(self.slots_for(logical_pages) < self.n_fast, TIER_FAST, TIER_SLOW)
 
-    def read_page(self, logical_page: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Host copy of one logical page's (k, v) contents — [L, page, nkv,
-        dh] each, independent of where the page physically lives. The
+    def read_page(self, logical_page: int) -> Tuple[np.ndarray, ...]:
+        """Host copy of one logical page's content, one array per content
+        pool (``(k, v)``, each [L, page, nkv, dh]; or ``(latent,)``),
+        independent of where the page physically lives. The
         migration-integrity tests read pages back across a migrate() and
         assert bit-equality."""
         slot = int(self.slot_of[int(logical_page)])
-        return np.asarray(self.k_pool[:, slot]), np.asarray(self.v_pool[:, slot])
+        return tuple(np.asarray(p[:, slot]) for p in self.pools[: self.n_content])

@@ -4,7 +4,10 @@
     PYTHONPATH=src python -m repro.launch.serve --smoke    # toy widths (CPU)
 
 Builds the model (``--arch``, qwen2.5-3b by default, at its published widths
-unless ``--smoke``; weights are random, made from ``--seed``), a queue-mode
+unless ``--smoke``; weights are random, made from ``--seed``; a model with
+latent attention, ``moonlight-16b-a3b``, holds ``--experts-held`` of each MoE
+layer's experts, by default 8 of 64: one chip's share of expert-parallel
+serving over eight, since all 64 do not fit one chip), a queue-mode
 MaxMem central manager over a fast and a slow slot range of the paged KV
 cache, registers a latency-sensitive (``ls``) and a best-effort (``be``)
 tenant, and runs continuous-batching decode with Quest page selection until
@@ -16,6 +19,7 @@ fast slots and the ``ls`` tenant's pages have to be migrated in.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 from typing import Callable, Optional
 
 import jax
@@ -37,9 +41,16 @@ SMOKE = dict(fast_pages=8, slow_pages=120, page_tokens=4, lanes=2,
              epoch_steps=4, queue_size=32)
 
 
+# experts held a MoE layer by a latent-attention model (full widths, --smoke)
+EXPERTS_HELD = (8, 2)
+
+
 def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--arch", default="qwen2.5-3b")
+    ap.add_argument("--experts-held", type=int, default=None,
+                    help=f"latent-attention MoE models: experts held a layer "
+                         f"(default {EXPERTS_HELD[0]}, {EXPERTS_HELD[1]} with --smoke)")
     ap.add_argument("--smoke", action="store_true",
                     help="toy widths and a small cache (CPU runs)")
     ap.add_argument("--seed", type=int, default=0)
@@ -60,6 +71,9 @@ def load_model(args: argparse.Namespace):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if cfg.is_mla:
+        held = args.experts_held if args.experts_held is not None else EXPERTS_HELD[args.smoke]
+        cfg = dataclasses.replace(cfg, experts_held=held)
     params = jax.jit(get_model(cfg).init)(jax.random.PRNGKey(args.seed))
     return cfg, params
 

@@ -159,13 +159,15 @@ def blocked_attention(
 ) -> jax.Array:
     """Memory-efficient (flash-style) attention in pure JAX.
 
-    q: [B, Sq, nh, dh]; k, v: [B, Skv, nkv, dh] with nh % nkv == 0.
+    q: [B, Sq, nh, dh]; k: [B, Skv, nkv, dh]; v: [B, Skv, nkv, dv] with
+    nh % nkv == 0 (dv may differ from dh: MLA's v is narrower than q·k).
     Online-softmax over kv blocks via lax.scan, so peak score memory is
     [B, nh, q_block, kv_block] rather than [B, nh, Sq, Skv].
-    Returns [B, Sq, nh, dh].
+    Returns [B, Sq, nh, dv].
     """
     B, Sq, nh, dh = q.shape
     Skv, nkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     scale = 1.0 / math.sqrt(dh)
 
     # GQA-expand KV to full heads: keeps the head dim uniform so TP sharding
@@ -189,7 +191,7 @@ def blocked_attention(
 
     qb = q.reshape(B, nq, q_block, nh, dh).transpose(0, 3, 1, 2, 4)  # [B,h,nq,qb,dh]
     kb = k.reshape(B, nk, kv_block, nh, dh).transpose(1, 0, 3, 2, 4)  # [nk,B,h,kb,dh]
-    vb = v.reshape(B, nk, kv_block, nh, dh).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(B, nk, kv_block, nh, dv).transpose(1, 0, 3, 2, 4)
 
     q_pos = q_offset + jnp.arange(nq * q_block).reshape(nq, q_block)
     kv_pos = jnp.arange(nk * kv_block).reshape(nk, kv_block)
@@ -229,12 +231,12 @@ def blocked_attention(
         )
         return (acc, m_new, l_new), None
 
-    acc0 = jnp.zeros((B, nh, nq, q_block, dh), sdt)
+    acc0 = jnp.zeros((B, nh, nq, q_block, dv), sdt)
     m0 = jnp.full((B, nh, nq, q_block), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, nh, nq, q_block), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(kv_step, (acc0, m0, l0), (kb, vb, kv_pos, kv_valid))
     out = acc / jnp.maximum(l[..., None], 1e-37)
-    out = out.transpose(0, 2, 3, 1, 4).reshape(B, nq * q_block, nh, dh)
+    out = out.transpose(0, 2, 3, 1, 4).reshape(B, nq * q_block, nh, dv)
     return out[:, :Sq].astype(q.dtype)
 
 

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import encdec, hybrid, ssm_lm, transformer
+from repro.models import deepseek, encdec, hybrid, ssm_lm, transformer
 
 
 @dataclass(frozen=True)
@@ -30,10 +30,23 @@ class ModelAPI:
     init_cache: Callable[..., Any]
     decode: Callable[..., Any]
     prefill: Optional[Callable[..., Any]] = None
+    # (params, tokens [B, S], last) -> (logits [B, V] f32 at ``last``, the
+    # rows of each paged-cache pool [L, B, S, *row], routed ids or None)
+    paged_prefill: Optional[Callable[..., Any]] = None
 
 
 def get_model(cfg: ModelConfig) -> ModelAPI:
     fam = cfg.family
+    if cfg.is_mla:  # DeepSeek-V3 block: latent attention, held-expert MoE
+        return ModelAPI(
+            cfg=cfg,
+            init=lambda rng: deepseek.init_params(rng, cfg),
+            loss=lambda p, b, **kw: deepseek.loss_fn(p, b, cfg, **kw),
+            init_cache=lambda bs, ml, **kw: deepseek.init_cache(cfg, bs, ml, **kw),
+            decode=lambda p, t, c: deepseek.decode_step(p, t, c, cfg),
+            prefill=lambda p, t, ml: deepseek.prefill_cache(p, t, cfg, ml),
+            paged_prefill=lambda p, t, last: deepseek.prefill(p, t, last, cfg),
+        )
     if fam in ("dense", "moe", "vlm"):
         return ModelAPI(
             cfg=cfg,
@@ -42,6 +55,7 @@ def get_model(cfg: ModelConfig) -> ModelAPI:
             init_cache=lambda bs, ml, **kw: transformer.init_kv_cache(cfg, bs, ml, **kw),
             decode=lambda p, t, c: transformer.decode_step(p, t, c, cfg),
             prefill=lambda p, t, ml: transformer.prefill(p, t, cfg, ml),
+            paged_prefill=lambda p, t, last: transformer.paged_prefill(p, t, last, cfg),
         )
     if fam == "ssm":
         return ModelAPI(

@@ -240,20 +240,29 @@ def shard_kv_cache(cache: KVCache) -> KVCache:
 
 def prefill(params: Params, tokens: jax.Array, cfg, max_len: int):
     """Process a full prompt; returns (last_logits, KVCache of size max_len)."""
-    B, S = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = embed_tokens(params, tokens, cfg)
-    h, _, kv = forward_hidden(params, x, cfg, positions, remat="none", collect_kv=True)
-    k, v = kv  # [L, B, S, nkv, dh]
+    S = tokens.shape[1]
+    logits, (k, v), _ = paged_prefill(params, tokens, S - 1, cfg)
     pad = max_len - S
     if pad > 0:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    cache = shard_kv_cache(
-        KVCache(k=k.astype(cfg.cdtype), v=v.astype(cfg.cdtype), pos=jnp.asarray(S, jnp.int32))
-    )
-    logits = (h[:, -1:] @ lm_head_weight(params, cfg)).astype(jnp.float32)
-    return logits, cache
+    cache = shard_kv_cache(KVCache(k=k, v=v, pos=jnp.asarray(S, jnp.int32)))
+    return logits[:, None], cache
+
+
+def paged_prefill(params: Params, tokens: jax.Array, last, cfg):
+    """The forward pass of prompts [B, S] for a paged cache: (logits [B, V]
+    f32 at position ``last``, the cache rows ``(k, v)`` [L, B, S, nkv, dh],
+    no routed ids). Padding past ``last`` stays out of every real position
+    of a dense model (causal attention); a capacity-routed MoE layer counts
+    it against its capacity."""
+    B, S = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    x = embed_tokens(params, tokens, cfg)
+    h, _, (k, v) = forward_hidden(params, x, cfg, positions, remat="none", collect_kv=True)
+    h = jax.lax.dynamic_index_in_dim(h, last, axis=1, keepdims=False)
+    logits = (h @ lm_head_weight(params, cfg)).astype(jnp.float32)
+    return logits, (k.astype(cfg.cdtype), v.astype(cfg.cdtype)), None
 
 
 def _block_decode_deferred(lp, x, cfg, k_cache, v_cache, pos):

@@ -22,6 +22,27 @@ Continuous batching: requests from multiple tenants (each with its own MaxMem
 
 A step-latency model (HBM vs host-DMA page reads) attributes per-tenant
 decode latency so QoS benchmarks can measure p50/p99 per tenant.
+
+Prefill runs the model's ``paged_prefill`` (``serving/paged_model.py``) on
+the prompt padded to a multiple of ``prompt_bucket`` tokens (1: unpadded;
+with a larger bucket a few compiled programs serve every length, and
+:meth:`ServingEngine.warm` compiles them) and writes its cache rows into the
+request's pages in one device scatter. The model decides the cache's layout:
+grouped-query attention's ``(k, v)`` pools with Quest summaries, or one
+latent pool that multi-head latent attention decodes exactly over every
+page. A model that returns routed expert ids (DeepSeek-V3's held-expert
+MoE) has them counted: ``held_pairs`` is the routed pairs that hit an expert
+this chip holds.
+
+Each step is a ``jax.profiler.TraceAnnotation`` span ``serve.<step>``:
+``admit``, ``prefill``, ``decode``, ``record_access``, ``epoch`` and
+``finish``. Counters: ``tokens_decoded``, ``prompt_tokens_prefilled``,
+``held_pairs``, ``kv_pages_moved``, ``admission_blocked`` (:meth:`counters`).
+With ``keep_logits_every`` > 0 every request keeps its logits at the
+prefill's last position, at every that-many-th decode position after it and
+at its last position (``Request.logits``), and, for an MLA model, its routed
+ids at every position (``Request.route_ids``): the record an audit against
+a reference reads.
 """
 from __future__ import annotations
 
@@ -36,8 +57,9 @@ import numpy as np
 from repro.core.manager import CentralManager, TenantHandle
 from repro.core.types import TIER_FAST
 from repro.kvcache.paged import TieredPagedKV
-from repro.models.model import get_model
-from repro.serving.paged_model import PagedPools, paged_decode_step
+from repro.serving.paged_model import paged_decode_step, paged_prefill
+
+span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -53,6 +75,16 @@ class Request:
     submit_step: int = 0
     admit_step: int = -1
     finish_step: int = -1
+    # kept with keep_logits_every > 0: position -> f32[V] logits, and routed
+    # ids [L_moe, positions, k] in position order (MLA)
+    logits: Dict[int, np.ndarray] = dataclasses.field(default_factory=dict)
+    routes: List[np.ndarray] = dataclasses.field(default_factory=list)
+    tail: Optional[tuple] = None  # (position, logits) of the latest decode step
+    pages_moved: int = 0  # its pages that changed tier while it held them
+
+    @property
+    def route_ids(self) -> Optional[np.ndarray]:
+        return np.concatenate(self.routes, axis=1) if self.routes else None
 
     @property
     def queue_delay_steps(self) -> int:
@@ -82,13 +114,16 @@ class ServingEngine:
         fast_page_s: float = 1e-6,
         slow_page_s: float = 20e-6,
         seed: int = 0,
+        prompt_bucket: int = 1,
+        keep_logits_every: int = 0,
     ):
         self.cfg = cfg
         self.params = params
         self.manager = manager
         self.kv = kv
-        self.api = get_model(cfg)
-        self._prefill = jax.jit(self.api.prefill, static_argnums=(2,))
+        self.prompt_bucket = prompt_bucket
+        self.keep_logits_every = keep_logits_every
+        self.held_lo = cfg.expert_rank * cfg.held_experts if cfg.experts_held else 0
         self.max_batch = max_batch
         self.n_p = pages_per_seq
         self.quest_pages = quest_pages
@@ -106,6 +141,13 @@ class ServingEngine:
         self._latencies: Dict[str, List[float]] = {}
         self._migrated_pages = 0
         self.admission_blocked = 0  # allocation-failure backpressure events
+        self.tokens_decoded = 0
+        self.prompt_tokens_prefilled = 0
+        self.held_pairs = 0
+        self.decode_steps = 0
+        self.decode_context_tokens = 0  # sum over decode steps of active context lengths
+        self.held_expert_reads = 0  # distinct (layer, held expert) a decode step's tokens hit
+        self.prefill_sq_tokens = 0  # sum over prefills of prompt length squared
         self._epoch_log: List[dict] = []
         self.finished: List[Request] = []
         self.last_logits: Optional[np.ndarray] = None  # [B, V] of last step
@@ -144,6 +186,10 @@ class ServingEngine:
 
     # ------------------------------------------------------------- admission
     def _admit(self) -> None:
+        with span("serve.admit"):
+            self._admit_queued()
+
+    def _admit_queued(self) -> None:
         free_lanes = [i for i, r in enumerate(self.lanes) if r is None]
         blocked: List[Request] = []
         while free_lanes and self.queue:
@@ -167,23 +213,61 @@ class ServingEngine:
             self.lanes[lane] = req
             self.tables[lane, :] = -1
             self.tables[lane, :n_pages] = req.pages
-            # Prefill: dense forward collecting KV, then scatter into pages.
-            logits, cache = self._prefill(
-                self.params, jnp.asarray(req.prompt[None, :]), S
-            )
-            k, v = cache.k, cache.v  # [L, 1, S, nkv, dh]
-            self.kv.write_tokens(
-                (k, v), np.asarray([req.pages], np.int32), start_pos=0
-            )
+            with span("serve.prefill"):
+                logits = self._prefill_into_pages(req)
             # prefill accesses: every page of the prompt touched once
             counts = np.zeros(self.manager.num_pages, np.int64)
             counts[req.pages] += 1
-            self.manager.record_access(counts)
-            first = int(np.argmax(np.asarray(logits[0])))
-            req.generated.append(first)
+            with span("serve.record_access"):
+                self.manager.record_access(counts)
+            if self.keep_logits_every:
+                req.logits[S - 1] = logits
+            req.generated.append(int(np.argmax(logits)))
             self.positions[lane] = S  # next token index to write
+            self.prompt_tokens_prefilled += S
+            self.prefill_sq_tokens += S * S
         for req in reversed(blocked):
             self.queue.appendleft(req)
+
+    def _bucket(self, S: int) -> int:
+        b = self.prompt_bucket
+        return -(-S // b) * b
+
+    def _prefill_into_pages(self, req: Request) -> np.ndarray:
+        """Forward of the padded prompt, its first ``S`` cache rows
+        scattered into the request's pages; returns the f32 logits at the
+        last position."""
+        S = len(req.prompt)
+        toks = np.zeros((1, self._bucket(S)), np.int32)
+        toks[0, :S] = req.prompt
+        logits, rows, ids = paged_prefill(self.params, jnp.asarray(toks),
+                                          jnp.asarray(np.int32(S - 1)), cfg=self.cfg)
+        self.kv.write_tokens(rows, np.asarray([req.pages], np.int32), length=S)
+        if ids is not None:
+            ids = np.asarray(ids)[:, :S]  # [L_moe, S, k]
+            self.held_pairs += int(self._held(ids).sum())
+            if self.keep_logits_every:
+                req.routes.append(ids.astype(np.int16))
+        return np.asarray(logits[0], np.float32)
+
+    def _held(self, ids: np.ndarray) -> np.ndarray:
+        local = ids - self.held_lo
+        return (local >= 0) & (local < self.cfg.held_experts)
+
+    def warm(self, max_prompt: int, max_moves: int) -> None:
+        """Compile every prefill bucket up to ``max_prompt`` tokens and the
+        page scrubs and moves up to ``max_moves`` pages, writing nothing."""
+        for S in range(self.prompt_bucket, self._bucket(max_prompt) + 1, self.prompt_bucket):
+            _, rows, _ = paged_prefill(self.params, jnp.zeros((1, S), jnp.int32),
+                                       jnp.asarray(np.int32(0)), cfg=self.cfg)
+            self.kv.write_tokens(rows, np.full((1, self.n_p), -1, np.int32))
+        self.kv.warm(max(max_moves, self.n_p))
+
+    def counters(self) -> Dict[str, int]:
+        return {"tokens_decoded": self.tokens_decoded,
+                "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
+                "held_pairs": self.held_pairs, "kv_pages_moved": self._migrated_pages,
+                "admission_blocked": self.admission_blocked}
 
     # ------------------------------------------------------------- stepping
     def _ensure_page(self, lane: int) -> bool:
@@ -225,22 +309,33 @@ class ServingEngine:
             np.int32,
         )
         slot_tables = np.where(self.tables >= 0, self.kv.slot_of[np.maximum(self.tables, 0)], -1)
-        logits, pools, counts = paged_decode_step(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(self.positions),
-            jnp.asarray(slot_tables.astype(np.int32)),
-            jnp.asarray(self.tables),
-            jnp.asarray(active_mask),
-            PagedPools(self.kv.k_pool, self.kv.v_pool, self.kv.k_max, self.kv.k_min),
-            num_logical_pages=self.manager.num_pages,
-            cfg=self.cfg,
-            quest_pages=self.quest_pages,
-        )
-        self.kv.k_pool, self.kv.v_pool = pools.k, pools.v
-        self.kv.k_max, self.kv.k_min = pools.kmax, pools.kmin
-        counts_np = np.asarray(counts, np.int64)
-        self.manager.record_access(counts_np)
+        with span("serve.decode"):
+            out = paged_decode_step(
+                self.params,
+                jnp.asarray(tokens),
+                jnp.asarray(self.positions),
+                jnp.asarray(slot_tables.astype(np.int32)),
+                jnp.asarray(self.tables),
+                jnp.asarray(active_mask),
+                self.kv.pools,
+                num_logical_pages=self.manager.num_pages,
+                cfg=self.cfg,
+                quest_pages=self.quest_pages,
+            )
+            logits, pools, counts = out[:3]
+            self.kv.pools = tuple(pools)
+            counts_np = np.asarray(counts, np.int64)
+            self.last_logits = np.asarray(logits)
+            routes = np.asarray(out[3]) if len(out) > 3 else None  # [L_moe, B, k]
+        with span("serve.record_access"):
+            self.manager.record_access(counts_np)
+        self.decode_steps += 1
+        self.decode_context_tokens += int((self.positions + 1)[active_mask].sum())
+        if routes is not None:
+            held = self._held(routes) & active_mask[None, :, None]
+            self.held_pairs += int(held.sum())
+            self.held_expert_reads += sum(
+                len(np.unique(routes[l][held[l]])) for l in range(routes.shape[0]))
 
         # ---- latency attribution: page tiers touched this step -------------
         lat: Dict[str, StepLatency] = {}
@@ -256,46 +351,72 @@ class ServingEngine:
                 self._latencies[name].append(sec)
 
         # ---- token bookkeeping ---------------------------------------------
-        self.last_logits = np.asarray(logits)
         greedy = np.argmax(self.last_logits, axis=-1)
+        every = self.keep_logits_every
         for lane, req in enumerate(self.lanes):
             if req is None or not active_mask[lane]:
                 continue
+            pos = int(self.positions[lane])
             req.generated.append(int(greedy[lane]))
+            self.tokens_decoded += 1
+            last = len(req.generated) >= req.max_new_tokens
+            if every:
+                if routes is not None:
+                    req.routes.append(routes[:, lane, None].astype(np.int16))
+                if (pos - len(req.prompt) + 1) % every == 0:
+                    req.logits[pos] = self.last_logits[lane].copy()
+                req.tail = (pos, self.last_logits[lane])
             self.positions[lane] += 1
-            if len(req.generated) >= req.max_new_tokens:
+            if last:
                 self._finish(lane)
 
         self.step_count += 1
         # ---- MaxMem epoch ----------------------------------------------------
         if self.step_count % self.epoch_steps == 0:
-            res = self.manager.run_epoch()
-            if res.stats.queue is not None:
-                # queue mode: only the DRAINED batch moves bytes this epoch
-                # (commit-on-completion); enqueued selections still in
-                # flight keep serving from their source tier
-                q = res.stats.queue
-                moved = self.kv.apply_drained(
-                    q.drained_promote_ids, q.drained_demote_ids, self.manager
-                )
-            else:
-                moved = self.kv.migrate(res.plan, self.manager)
-            self._migrated_pages += moved
-            self._epoch_log.append(
-                {
-                    "step": self.step_count,
-                    "moved": moved,
-                    "queue_depth": res.queue_depth,
-                    "fmmr": {
-                        n: float(self.manager.fmmr_of(h))
-                        for n, h in self.tenant_handles.items()
-                    },
-                }
-            )
+            with span("serve.epoch"):
+                self._epoch()
         return lat
 
+    def _epoch(self) -> None:
+        res = self.manager.run_epoch()
+        if res.stats.queue is not None:
+            # queue mode: only the DRAINED batch moves bytes this epoch
+            # (commit-on-completion); enqueued selections still in
+            # flight keep serving from their source tier
+            q = res.stats.queue
+            ids = (np.asarray(q.drained_promote_ids), np.asarray(q.drained_demote_ids))
+            moved = self.kv.apply_drained(*ids, self.manager)
+        else:
+            ids = (np.asarray(res.plan.promote), np.asarray(res.plan.demote))
+            moved = self.kv.migrate(res.plan, self.manager)
+        self._migrated_pages += moved
+        flat = np.concatenate([a.ravel() for a in ids])
+        changed = set(flat[flat >= 0].tolist())
+        for req in self.lanes:
+            if req is not None and changed:
+                req.pages_moved += len(changed.intersection(req.pages))
+        self._epoch_log.append(
+            {
+                "step": self.step_count,
+                "moved": moved,
+                "queue_depth": res.queue_depth,
+                "fmmr": {
+                    n: float(self.manager.fmmr_of(h))
+                    for n, h in self.tenant_handles.items()
+                },
+            }
+        )
+
     def _finish(self, lane: int) -> None:
+        with span("serve.finish"):
+            self._release(lane)
+
+    def _release(self, lane: int) -> None:
         req = self.lanes[lane]
+        if req.tail is not None:  # its last position, however the request ends
+            pos, row = req.tail
+            req.logits[pos] = row.copy()
+            req.tail = None
         req.finish_step = self.step_count
         h = self.tenant_handles[req.tenant]
         if req.pages:

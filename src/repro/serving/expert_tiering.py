@@ -1,8 +1,8 @@
 """MoE expert-weight tiering — MaxMem's second Big-Data object (DESIGN §2).
 
 A *page* here is one (layer, expert) weight block (w_gate+w_up+w_down,
-~17 MB for moonshot) living in pooled storage: fast slots = HBM-resident,
-slow slots = host memory. Routing skew (top-k gating concentrates traffic on
+~17.3 MB for moonlight-16b-a3b's 2,048 x 1,408 experts in bf16) living in
+pooled storage: fast slots = HBM-resident, slow slots = host memory. Routing skew (top-k gating concentrates traffic on
 few experts) is the heat signal: each decode/prefill step's routed expert ids
 feed the central manager exactly like KV-page touches, and the policy
 migrates hot experts into the fast pool with the Pallas page_move kernel.

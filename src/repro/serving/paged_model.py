@@ -1,6 +1,6 @@
 """Jitted batched decode over the tiered paged KV cache.
 
-Per layer and step:
+Grouped-query attention, per layer and step:
   1. project q/k/v for the new token; write k/v into the current page slot
   2. update the page's Quest summaries (key min/max)
   3. score all pages of each sequence with the Quest upper bound
@@ -8,6 +8,16 @@ Per layer and step:
      and select the top-``quest_pages`` pages (current page force-included)
   4. gather ONLY the selected pages and run masked decode attention
   5. emit the selected logical page ids -> per-page access counts
+
+Multi-head latent attention (``cfg.is_mla``) takes the exact path whatever
+``quest_pages`` says (the latent pool keeps no Quest summaries): every valid
+page of a sequence is gathered, the new token's latent is placed among
+them, attention is absorbed (``models/deepseek.py``), and every valid page
+is reported touched once a layer. After the layer scan one scatter writes
+every layer's new latent into its page slot.
+
+:func:`paged_prefill` is the prefill of one prompt for either layout, its
+cache rows in the pools' order (``ModelAPI.paged_prefill``).
 
 The per-page access counts are the PEBS-analogue stream MaxMem samples: with
 top-k selection, page touches are heat-skewed, which is exactly what makes
@@ -22,7 +32,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kvcache.paged import put_tokens
+from repro.models import deepseek
 from repro.models import layers as L
+from repro.models.model import get_model
 from repro.models.moe import moe_mlp
 from repro.models.transformer import lm_head_weight
 
@@ -36,7 +49,16 @@ class PagedPools(NamedTuple):
     kmin: jax.Array
 
 
-@partial(jax.jit, static_argnames=("cfg", "quest_pages", "num_logical_pages"))
+@partial(jax.jit, static_argnames=("cfg",))
+def paged_prefill(params, tokens: jax.Array, last: jax.Array, cfg):
+    """tokens [1, S] (a prompt padded at its end) -> (logits [1, V] f32 at
+    position ``last``, the rows of each pool [L, 1, S, *row], routed ids
+    [L_moe, S, k] or None)."""
+    return get_model(cfg).paged_prefill(params, tokens, last)
+
+
+@partial(jax.jit, static_argnames=("cfg", "quest_pages", "num_logical_pages"),
+         donate_argnames=("pools",))
 def paged_decode_step(
     params,
     tokens: jax.Array,  # [B] int32
@@ -44,12 +66,17 @@ def paged_decode_step(
     slot_tables: jax.Array,  # [B, n_p] int32 physical slots (-1 = no page)
     logical_tables: jax.Array,  # [B, n_p] int32 logical page ids (-1 = none)
     active: jax.Array,  # [B] bool
-    pools: PagedPools,
+    pools,  # (k, v, kmax, kmin), or (latent,) for MLA; donated
     num_logical_pages: int = 0,
     cfg=None,
     quest_pages: int = 4,
 ):
-    """Returns (logits [B, V], pools', access_counts [P_logical] i32)."""
+    """Returns (logits [B, V], pools', access_counts [P_logical] i32); an
+    MLA model adds the routed expert ids of its MoE layers, [L_moe, B, k]."""
+    if cfg.is_mla:
+        return _mla_decode(params, tokens, positions, slot_tables, logical_tables, active,
+                           pools, num_logical_pages, cfg)
+    pools = PagedPools(*pools)
     B = tokens.shape[0]
     page = pools.k.shape[2]
     n_p = slot_tables.shape[1]
@@ -157,3 +184,43 @@ def paged_decode_step(
     # this, and callers never consume dead-lane logits anyway).
     logits = jnp.where(active[:, None], logits, 0.0)
     return logits, PagedPools(*new_pools), counts[:-1]
+
+
+def _mla_decode(params, tokens, positions, slot_tables, logical_tables, active, pools,
+                num_logical_pages, cfg):
+    (lat_pool,) = pools  # [L, n_slots, page, latent_dim]
+    B, n_p = slot_tables.shape
+    n_slots, page = lat_pool.shape[1], lat_pool.shape[2]
+    cur_p, cur_off = positions // page, positions % page
+    cur_slot = jnp.take_along_axis(slot_tables, cur_p[:, None], axis=1)[:, 0]
+    # idle lanes write out of range: the scatter drops them
+    write_slot = jnp.where(active, jnp.maximum(cur_slot, 0), n_slots)
+    st = jnp.maximum(slot_tables, 0)
+    T = n_p * page
+    mask = ((jnp.arange(T)[None, :] <= positions[:, None]) & active[:, None]
+            & jnp.repeat(slot_tables >= 0, page, axis=1))
+    rows = jnp.arange(B)
+
+    def put(ctx, new):  # this token's latent among the gathered pages
+        new = jnp.pad(new.astype(ctx.dtype), ((0, 0), (0, ctx.shape[-1] - new.shape[-1])))
+        return ctx.at[rows, cur_p * page + cur_off].set(new)
+
+    def body(x, inp):
+        lp, layer = inp
+        with jax.named_scope("mla.attend"):
+            ctx = lat_pool[layer, st].reshape(B, T, -1)  # every page of the table
+        x, new, ids = deepseek.decode_layer(lp, x, positions, ctx, mask, put, cfg)
+        return x, (new, ids)
+
+    k0, L = cfg.first_k_dense_replace, cfg.num_layers
+    x = params["embed"][tokens].astype(cfg.cdtype)
+    x, (lat0, _) = jax.lax.scan(body, x, (params["dense_layers"], jnp.arange(k0)))
+    x, (lat1, ids) = jax.lax.scan(body, x, (params["moe_layers"], jnp.arange(k0, L)))
+    with jax.named_scope("mla.latent_write"):
+        lat_pool = put_tokens(lat_pool, write_slot, cur_off, jnp.concatenate([lat0, lat1]))
+    # every valid page of an active sequence is read in every layer
+    touched = (slot_tables >= 0) & (jnp.arange(n_p)[None, :] <= cur_p[:, None]) & active[:, None]
+    idx = jnp.where(touched, logical_tables, num_logical_pages)
+    counts = jnp.zeros((num_logical_pages + 1,), jnp.int32).at[idx.reshape(-1)].add(L, mode="drop")
+    logits = jnp.where(active[:, None], deepseek.head(params, x, cfg), 0.0)
+    return logits, (lat_pool,), counts[:-1], ids

@@ -89,5 +89,5 @@ class TestRoofline:
         assert f_train >= 6.0 * n * 256 * 4096
 
     def test_moe_uses_active_params(self):
-        cfg = get_config("moonshot-v1-16b-a3b")
+        cfg = get_config("moonlight-16b-a3b")
         assert cfg.active_param_count() < 0.3 * cfg.param_count()

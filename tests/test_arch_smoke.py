@@ -70,9 +70,8 @@ def test_param_counts_match_analytic():
         "yi-6b": (5.5e9, 7.5e9),
         "qwen2.5-32b": (30e9, 36e9),
         "mamba2-130m": (0.10e9, 0.16e9),
-        # NOTE: assignment specifies 48L (the hf checkpoint has 27); with the
-        # assigned depth total params land at ~29B (active ~4B).
-        "moonshot-v1-16b-a3b": (24e9, 32e9),
+        # the published config.json: 27 layers, MLA, 64 experts -> 15.96B
+        "moonlight-16b-a3b": (15.5e9, 16.5e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()

@@ -7,6 +7,7 @@ topology is described inside a fixture, never while a module is imported,
 so every test worker collects the same tests and only the worker that runs
 this file loads the TPU library.
 """
+import dataclasses
 import os
 
 import jax
@@ -19,6 +20,8 @@ from repro.kernels.page_copy import page_copy, page_move
 
 QWEN = get_config("qwen2.5-3b")
 KV_ROWS = QWEN.num_layers * 1024  # one row per (layer, slot) of a 1024-slot pool
+MOON = dataclasses.replace(get_config("moonlight-16b-a3b"), experts_held=8)
+MOON_SLOTS = 12_288  # bench/configs/moonlight_ep8.json's KV pool
 DATA_PLANE_ROWS = 458_752 + 65_536 + 1  # paper geometry: slow + fast + trash
 
 
@@ -49,12 +52,41 @@ def _compile(fn, args):
     ("data_plane", (128,), jnp.float32, 2048),
     ("qwen_kv", (16, QWEN.num_kv_heads, QWEN.d_head), jnp.bfloat16, QWEN.num_layers * 64),
     ("qwen_quest_summary", (QWEN.num_kv_heads, QWEN.d_head), jnp.float32, QWEN.num_layers * 64),
+    ("moonlight_latent", (16, 640), jnp.bfloat16, MOON.num_layers * 64),
 ])
 def test_page_move_compiles(one_chip, name, row, dtype, m):
-    rows = DATA_PLANE_ROWS if name == "data_plane" else KV_ROWS
+    rows = {"data_plane": DATA_PLANE_ROWS,
+            "moonlight_latent": MOON.num_layers * MOON_SLOTS}.get(name, KV_ROWS)
     pool = jax.ShapeDtypeStruct((rows, *row), dtype, sharding=one_chip)
     ids = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
     _compile(page_move, (pool, ids, ids))
+
+
+def test_mla_decode_step_updates_the_latent_pool_in_place(one_chip):
+    """moonlight_ep8's decode step (32 lanes, 512-page tables, 8 experts
+    held, a 12,288-page latent pool) compiles for the v5e with the pool
+    aliased and temporaries below 0.5 GB (one layer's gathered pages are
+    0.34 GB). A pool whose rows are not whole 128-lane tiles, or a scatter
+    into it by two index arrays, makes XLA copy all 6.8 GB of it."""
+    from repro.kvcache.paged import lane_width
+    from repro.models.model import get_model
+    from repro.serving.paged_model import paged_decode_step
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          jax.eval_shape(get_model(MOON).init, jax.random.PRNGKey(0)))
+    B, n_p = 32, 512
+    pool = spec((MOON.num_layers, MOON_SLOTS, 16, lane_width(MOON.latent_dim)), jnp.bfloat16)
+    table = spec((B, n_p), jnp.int32)
+    lane = spec((B,), jnp.int32)
+    mem = paged_decode_step.lower(
+        params, lane, lane, table, table, spec((B,), jnp.bool_), (pool,),
+        num_logical_pages=MOON_SLOTS, cfg=MOON, quest_pages=n_p).compile().memory_analysis()
+    pool_bytes = MOON.num_layers * MOON_SLOTS * 16 * 640 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
 
 
 def test_page_copy_compiles_at_data_plane_width(one_chip):
