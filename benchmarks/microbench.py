@@ -477,14 +477,15 @@ def run() -> Rows:
     us = _time(lambda: flash_attention(q, k, v, q_blk=128, kv_blk=128), n=5)
     rows.add("micro_flash_attn_512_interpret", us, "B1_h4_dh64")
 
-    # paged attention kernel (interpret)
-    kp = jax.random.normal(ks[1], (64, 16, 2, 64), jnp.float32)
-    vp = jax.random.normal(ks[2], (64, 16, 2, 64), jnp.float32)
-    qd = jax.random.normal(ks[0], (4, 4, 64), jnp.float32)
-    tables = jnp.asarray(rng.integers(0, 64, (4, 8)), jnp.int32)
+    # latent paged attention kernel (interpret): 4 lanes, 16 heads, 640-lane rows
+    pool = jax.random.normal(ks[1], (2, 64, 16, 640), jnp.float32)
+    qd = jax.random.normal(ks[0], (4, 16, 640), jnp.float32)
+    tables = jnp.asarray(rng.permutation(64)[:32].reshape(4, 8), jnp.int32)
     lens = jnp.asarray([128, 96, 64, 32], jnp.int32)
-    us = _time(lambda: paged_attention(qd, kp, vp, tables, lens), n=5)
-    rows.add("micro_paged_attn_interpret", us, "B4_pages8x16")
+    new = jax.random.normal(ks[2], (4, 640), jnp.float32)
+    us = _time(lambda: paged_attention(qd, pool, jnp.int32(1), tables, lens, new, sm_scale=0.07,
+                                       v_width=512), n=5)
+    rows.add("micro_paged_attn_interpret", us, "B4_pages8x16_latent640")
     return rows
 
 

@@ -33,8 +33,10 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0, q_blk=256, kv_blk
     )
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
-    return _paged(q, k_pages, v_pages, block_tables, seq_lens, interpret=interpret())
+def paged_attention(q, pool, layer, tables, lens, new, *, sm_scale, v_width, block_pages=32):
+    """Latent decode attention read in place from the paged pool."""
+    return _paged(q, pool, layer, tables, lens, new, sm_scale=sm_scale, v_width=v_width,
+                  block_pages=block_pages, interpret=interpret())
 
 
 def hot_bins(page_ids, counts_in, *, num_bins=6, tile=512, n_chunk=1024):

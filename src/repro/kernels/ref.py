@@ -43,34 +43,33 @@ def flash_attention_ref(
 
 
 def paged_attention_ref(
-    q: jax.Array,  # [B, nh, dh] one query token per sequence
-    k_pages: jax.Array,  # [P, page, nkv, dh] global page pool
-    v_pages: jax.Array,  # [P, page, nkv, dh]
-    block_tables: jax.Array,  # [B, pages_per_seq] int32 page ids (-1 pad ok)
-    seq_lens: jax.Array,  # [B] int32 valid tokens per sequence
+    q: jax.Array,  # [B, nh, W] one query row per head and lane
+    pool: jax.Array,  # [L, n_slots, page, W] latent rows
+    layer: int,
+    tables: jax.Array,  # [B, n_p] int32 slots (-1 = no page)
+    lens: jax.Array,  # [B] int32 valid tokens per lane
+    new: jax.Array | None = None,  # [B, W] one more token per lane
+    *,
+    sm_scale: float,
+    v_width: int,
 ) -> jax.Array:
-    B, nh, dh = q.shape
-    P, page, nkv, _ = k_pages.shape
-    n_p = block_tables.shape[1]
-    g = nh // nkv
-    tables = jnp.maximum(block_tables, 0)
-    k = k_pages[tables]  # [B, n_p, page, nkv, dh]
-    v = v_pages[tables]
-    k = k.reshape(B, n_p * page, nkv, dh)
-    v = v.reshape(B, n_p * page, nkv, dh)
-    qg = q.reshape(B, nkv, g, dh)
-    s = jnp.einsum("bngd,bknd->bngk", qg, k, preferred_element_type=jnp.float32)
-    s = s / math.sqrt(dh)
-    pos = jnp.arange(n_p * page)[None, :]
-    valid = pos < seq_lens[:, None]
-    valid &= (block_tables >= 0)[:, :, None].repeat(page, axis=2).reshape(B, -1)
-    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    """Keys are all W lanes of a row, values its first ``v_width``; returns
+    [B, nh, v_width] float32 (0 for a lane with no token)."""
+    B, nh, W = q.shape
+    n_p, page = tables.shape[1], pool.shape[2]
+    ctx = pool[layer][jnp.maximum(tables, 0)].reshape(B, n_p * page, W)
+    valid = jnp.arange(n_p * page)[None, :] < lens[:, None]
+    valid &= jnp.repeat(tables >= 0, page, axis=1)
+    if new is not None:
+        ctx = jnp.concatenate([ctx, new[:, None].astype(ctx.dtype)], axis=1)
+        valid = jnp.concatenate([valid, jnp.ones((B, 1), bool)], axis=1)
+    ctx = jnp.where(valid[..., None], ctx, 0).astype(q.dtype)  # rows past the length: unread
+    s = jnp.einsum("bhw,btw->bht", q, ctx, preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(valid[:, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # rows with zero valid keys
-    out = jnp.einsum(
-        "bngk,bknd->bngd", p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    return out.reshape(B, nh, dh).astype(q.dtype)
+    return jnp.einsum("bht,btv->bhv", p.astype(q.dtype), ctx[..., :v_width],
+                      preferred_element_type=jnp.float32)
 
 
 def hot_bins_ref(

@@ -164,28 +164,46 @@ def mla_attend_full(p: Params, q_nope, q_rope, latent, cfg) -> jax.Array:
         return o.reshape(B, S, nh * dv) @ p["w_o"]
 
 
+def absorbed_query(p: Params, q_nope, q_rope, width: int, cfg) -> jax.Array:
+    """One query per row in latent form, ``q_nope W_UK || q_rope``, zero-padded
+    to ``width`` lanes: [B, nh, width] in ``cfg.cdtype``."""
+    B, nh, dn = q_nope.shape
+    c, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    w = p["w_kvb"].reshape(c, nh, dn + dv)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w[..., :dn], preferred_element_type=F32)
+    pad = jnp.zeros((B, nh, width - c - q_rope.shape[-1]), F32)
+    return jnp.concatenate([q_lat, q_rope.astype(F32), pad], axis=-1).astype(cfg.cdtype)
+
+
+def absorbed_output(p: Params, o_lat, cfg) -> jax.Array:
+    """Attention-weighted ``c_kv`` [B, nh, kv_lora_rank] through ``W_UV`` and
+    ``o_proj``: [B, d]."""
+    B, nh, c = o_lat.shape
+    dn, dv, dt = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.cdtype
+    w = p["w_kvb"].reshape(c, nh, dn + dv)
+    o = jnp.einsum("bhc,chv->bhv", o_lat.astype(dt), w[..., dn:], preferred_element_type=F32)
+    return o.reshape(B, nh * dv).astype(dt) @ p["w_o"]
+
+
+def mla_scale(cfg) -> float:
+    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
 def mla_attend_latent(p: Params, q_nope, q_rope, lat, mask, cfg) -> jax.Array:
     """Absorbed attention of one query per row over cached latents.
 
     q_nope [B,nh,dn], q_rope [B,nh,dr], lat [B,T,latent_dim] (any storage
     dtype, computed in ``cfg.cdtype``; lanes past ``latent_dim`` are
     ignored), mask [B,T]. Returns [B, d]."""
-    B, nh, dn = q_nope.shape
-    c, dv, dt = cfg.kv_lora_rank, cfg.v_head_dim, cfg.cdtype
-    scale = 1.0 / math.sqrt(dn + cfg.qk_rope_head_dim)
+    c, dt = cfg.kv_lora_rank, cfg.cdtype
     with jax.named_scope("mla.attend"):
-        w = p["w_kvb"].reshape(c, nh, dn + dv)
-        q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w[..., :dn], preferred_element_type=F32)
-        pad = jnp.zeros((B, nh, lat.shape[-1] - c - q_rope.shape[-1]), F32)
-        q = jnp.concatenate([q_lat, q_rope.astype(F32), pad], axis=-1).astype(dt)
+        q = absorbed_query(p, q_nope, q_rope, lat.shape[-1], cfg)
         lat = lat.astype(dt)
-        s = jnp.einsum("bhc,btc->bht", q, lat, preferred_element_type=F32) * scale
+        s = jnp.einsum("bhc,btc->bht", q, lat, preferred_element_type=F32) * mla_scale(cfg)
         s = jnp.where(mask[:, None, :], s, -jnp.inf)
         pr = jax.nn.softmax(s, axis=-1)
         o_lat = jnp.einsum("bht,btc->bhc", pr.astype(dt), lat, preferred_element_type=F32)
-        o = jnp.einsum("bhc,chv->bhv", o_lat[..., :c].astype(dt), w[..., dn:],
-                       preferred_element_type=F32)
-        return o.reshape(B, nh * dv).astype(dt) @ p["w_o"]
+        return absorbed_output(p, o_lat[..., :c], cfg)
 
 
 # --------------------------------------------------------------------------- MoE
@@ -302,14 +320,14 @@ def prefill_cache(params: Params, tokens: jax.Array, cfg, max_len: int):
     return logits[:, None], LatentCache(lat=lat, pos=jnp.asarray(S, jnp.int32))
 
 
-def decode_layer(lp: Params, x: jax.Array, positions: jax.Array, lat_ctx, mask, put, cfg):
-    """One layer for one new token per row. x [B, d]; ``lat_ctx`` the
-    context's latents [B, T, latent_dim] without this token; ``put(ctx, new)``
-    places this token's latent among them. Returns (x, new latent, ids)."""
+def decode_layer(lp: Params, x: jax.Array, positions: jax.Array, attend, cfg):
+    """One layer for one new token per row. x [B, d]; ``attend(p, q_nope,
+    q_rope, new)`` is the attention output [B, d] of each row's query over
+    its context and this token's latent ``new`` [B, latent_dim]. Returns (x,
+    new latent, ids)."""
     h = L.rms_norm(x[:, None], lp["attn_norm"], cfg.norm_eps)
     q_nope, q_rope, lat = mla_project(lp["attn"], h, positions[:, None], cfg)
-    ctx = put(lat_ctx, lat[:, 0])
-    x = x + mla_attend_latent(lp["attn"], q_nope[:, 0], q_rope[:, 0], ctx, mask, cfg)
+    x = x + attend(lp["attn"], q_nope[:, 0], q_rope[:, 0], lat[:, 0])
     m, ids = mlp_block(lp, L.rms_norm(x[:, None], lp["mlp_norm"], cfg.norm_eps), cfg)
     return x + m[:, 0], lat[:, 0], ids
 
@@ -327,7 +345,11 @@ def decode_step(params: Params, token: jax.Array, cache: LatentCache, cfg):
 
     def body(x, inp):
         lp, lat_l = inp
-        x, new, _ = decode_layer(lp, x, positions, lat_l, mask, put, cfg)
+
+        def attend(p, q_nope, q_rope, new):
+            return mla_attend_latent(p, q_nope, q_rope, put(lat_l, new), mask, cfg)
+
+        x, new, _ = decode_layer(lp, x, positions, attend, cfg)
         return x, put(lat_l, new)
 
     k0 = cfg.first_k_dense_replace

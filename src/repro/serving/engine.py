@@ -37,7 +37,10 @@ this chip holds.
 Each step is a ``jax.profiler.TraceAnnotation`` span ``serve.<step>``:
 ``admit``, ``prefill``, ``decode``, ``record_access``, ``epoch`` and
 ``finish``. Counters: ``tokens_decoded``, ``prompt_tokens_prefilled``,
-``held_pairs``, ``kv_pages_moved``, ``admission_blocked`` (:meth:`counters`).
+``held_pairs``, ``kv_pages_moved``, ``admission_blocked`` and
+``decode_pages_read`` (:meth:`counters`): the pages latent attention's paged
+kernel reads in one layer, summed over decode steps (each active lane's
+pooled tokens in whole pages; 0 where Quest selects the pages).
 With ``keep_logits_every`` > 0 every request keeps its logits at the
 prefill's last position, at every that-many-th decode position after it and
 at its last position (``Request.logits``), and, for an MLA model, its routed
@@ -146,6 +149,7 @@ class ServingEngine:
         self.held_pairs = 0
         self.decode_steps = 0
         self.decode_context_tokens = 0  # sum over decode steps of active context lengths
+        self.decode_pages_read = 0  # pages the latent kernel reads a layer, over decode steps
         self.held_expert_reads = 0  # distinct (layer, held expert) a decode step's tokens hit
         self.prefill_sq_tokens = 0  # sum over prefills of prompt length squared
         self._epoch_log: List[dict] = []
@@ -267,7 +271,8 @@ class ServingEngine:
         return {"tokens_decoded": self.tokens_decoded,
                 "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
                 "held_pairs": self.held_pairs, "kv_pages_moved": self._migrated_pages,
-                "admission_blocked": self.admission_blocked}
+                "admission_blocked": self.admission_blocked,
+                "decode_pages_read": self.decode_pages_read}
 
     # ------------------------------------------------------------- stepping
     def _ensure_page(self, lane: int) -> bool:
@@ -331,6 +336,8 @@ class ServingEngine:
             self.manager.record_access(counts_np)
         self.decode_steps += 1
         self.decode_context_tokens += int((self.positions + 1)[active_mask].sum())
+        if self.cfg.is_mla:  # the paged kernel stops at each lane's last pooled page
+            self.decode_pages_read += int((-(-self.positions // self.kv.page))[active_mask].sum())
         if routes is not None:
             held = self._held(routes) & active_mask[None, :, None]
             self.held_pairs += int(held.sum())

@@ -10,11 +10,13 @@ Grouped-query attention, per layer and step:
   5. emit the selected logical page ids -> per-page access counts
 
 Multi-head latent attention (``cfg.is_mla``) takes the exact path whatever
-``quest_pages`` says (the latent pool keeps no Quest summaries): every valid
-page of a sequence is gathered, the new token's latent is placed among
-them, attention is absorbed (``models/deepseek.py``), and every valid page
-is reported touched once a layer. After the layer scan one scatter writes
-every layer's new latent into its page slot.
+``quest_pages`` says (the latent pool keeps no Quest summaries): attention
+is absorbed (``models/deepseek.py``) and the paged kernel
+(``kernels/paged_attention.py``) reads every valid page of each lane in
+place from the pool, up to the lane's own length, with the new token's
+latent folded into the same softmax; every valid page is reported touched
+once a layer. After the layer scan one scatter writes every layer's new
+latent into its page slot.
 
 :func:`paged_prefill` is the prefill of one prompt for either layout, its
 cache rows in the pools' order (``ModelAPI.paged_prefill``).
@@ -32,6 +34,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.kvcache.paged import put_tokens
 from repro.models import deepseek
 from repro.models import layers as L
@@ -190,26 +193,26 @@ def _mla_decode(params, tokens, positions, slot_tables, logical_tables, active, 
                 num_logical_pages, cfg):
     (lat_pool,) = pools  # [L, n_slots, page, latent_dim]
     B, n_p = slot_tables.shape
-    n_slots, page = lat_pool.shape[1], lat_pool.shape[2]
+    n_slots, page, width = lat_pool.shape[1:]
     cur_p, cur_off = positions // page, positions % page
     cur_slot = jnp.take_along_axis(slot_tables, cur_p[:, None], axis=1)[:, 0]
     # idle lanes write out of range: the scatter drops them
     write_slot = jnp.where(active, jnp.maximum(cur_slot, 0), n_slots)
-    st = jnp.maximum(slot_tables, 0)
-    T = n_p * page
-    mask = ((jnp.arange(T)[None, :] <= positions[:, None]) & active[:, None]
-            & jnp.repeat(slot_tables >= 0, page, axis=1))
-    rows = jnp.arange(B)
-
-    def put(ctx, new):  # this token's latent among the gathered pages
-        new = jnp.pad(new.astype(ctx.dtype), ((0, 0), (0, ctx.shape[-1] - new.shape[-1])))
-        return ctx.at[rows, cur_p * page + cur_off].set(new)
+    lens = jnp.where(active, positions, 0)  # tokens already in the pool
 
     def body(x, inp):
         lp, layer = inp
-        with jax.named_scope("mla.attend"):
-            ctx = lat_pool[layer, st].reshape(B, T, -1)  # every page of the table
-        x, new, ids = deepseek.decode_layer(lp, x, positions, ctx, mask, put, cfg)
+
+        def attend(p, q_nope, q_rope, new):  # this token joins the pool's pages
+            with jax.named_scope("mla.attend"):
+                q = deepseek.absorbed_query(p, q_nope, q_rope, width, cfg)
+                new = jnp.pad(new.astype(lat_pool.dtype), ((0, 0), (0, width - new.shape[-1])))
+                o_lat = ops.paged_attention(q, lat_pool, layer, slot_tables, lens, new,
+                                            sm_scale=deepseek.mla_scale(cfg),
+                                            v_width=cfg.kv_lora_rank)
+                return deepseek.absorbed_output(p, o_lat, cfg)
+
+        x, new, ids = deepseek.decode_layer(lp, x, positions, attend, cfg)
         return x, (new, ids)
 
     k0, L = cfg.first_k_dense_replace, cfg.num_layers

@@ -62,46 +62,87 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
 
 
-class TestPagedAttention:
-    @pytest.mark.parametrize("B,nh,nkv,dh,P,page,n_p", [
-        (2, 4, 2, 64, 16, 8, 4),
-        (3, 8, 1, 128, 32, 16, 6),
-        (1, 4, 4, 64, 8, 8, 2),
-        (4, 16, 2, 128, 64, 32, 8),
-    ])
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_sweep(self, B, nh, nkv, dh, P, page, n_p, dtype):
-        rng = np.random.default_rng(B * 131 + P)
-        ks = jax.random.split(jax.random.PRNGKey(7), 3)
-        q = jax.random.normal(ks[0], (B, nh, dh), dtype)
-        kp = jax.random.normal(ks[1], (P, page, nkv, dh), dtype)
-        vp = jax.random.normal(ks[2], (P, page, nkv, dh), dtype)
-        tables = np.full((B, n_p), -1, np.int32)
-        lens = np.zeros((B,), np.int32)
-        for b in range(B):
-            used = rng.integers(1, n_p + 1)
-            tables[b, :used] = rng.choice(P, used, replace=False)
-            lens[b] = rng.integers(1, used * page + 1)
-        out = paged_attention(q, kp, vp, jnp.asarray(tables), jnp.asarray(lens))
-        want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(tables), jnp.asarray(lens))
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(want, np.float32),
-            atol=_tol(dtype), rtol=_tol(dtype),
-        )
+PAGE, W, V = 4, 256, 128  # latent rows two lane tiles wide, values the first tile
+BLOCK = 3  # pages per kernel block: 12 tokens
 
-    def test_single_token_context(self):
-        """seq_len=1: only the first slot of the first page is valid."""
-        ks = jax.random.split(jax.random.PRNGKey(3), 3)
-        q = jax.random.normal(ks[0], (1, 2, 64), jnp.float32)
-        kp = jax.random.normal(ks[1], (4, 8, 2, 64), jnp.float32)
-        vp = jax.random.normal(ks[2], (4, 8, 2, 64), jnp.float32)
-        tables = jnp.asarray([[2, -1]], jnp.int32)
-        lens = jnp.asarray([1], jnp.int32)
-        out = paged_attention(q, kp, vp, tables, lens)
-        # attention over a single key = that key's value
-        np.testing.assert_allclose(
-            np.asarray(out[0, 0]), np.asarray(vp[2, 0, 0]), atol=1e-5, rtol=1e-5
-        )
+
+def _latent_case(dtype, lens, *, layer=0, holes=(), n_p=12, seed=0):
+    """Queries, new rows, a [3, 64, PAGE, W] pool, tables and lengths. Every
+    slot no table names below its lane's length holds NaN (slot 0 among
+    them): one read of it, even masked, turns the output NaN."""
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    B, nh, n_slots = len(lens), 4, 64
+    q = jax.random.normal(ks[0], (B, nh, W), dtype)
+    new = jax.random.normal(ks[2], (B, W), dtype)
+    tables = np.zeros((B, n_p), np.int32)  # past the length: slot 0, all NaN
+    free = list(rng.permutation(np.arange(1, n_slots)))
+    for b, n in enumerate(lens):
+        for p in range(-(-n // PAGE)):
+            tables[b, p] = free.pop()
+    for b, p in holes:
+        tables[b, p] = -1
+    read = sorted({int(t) for b, n in enumerate(lens) for t in tables[b, :-(-n // PAGE)] if t >= 0})
+    pool = np.full((3, n_slots, PAGE, W), np.nan, np.float32)
+    pool[:, read] = np.asarray(jax.random.normal(ks[1], (3, len(read), PAGE, W)), np.float32)
+    return (q, jnp.asarray(pool, dtype), layer, jnp.asarray(tables),
+            jnp.asarray(np.asarray(lens, np.int32)), new)
+
+
+def _latent(args, block_pages=BLOCK):
+    q, pool, layer, tables, lens, new = args
+    out = paged_attention(q, pool, jnp.int32(layer), tables, lens, new, sm_scale=0.125,
+                          v_width=V, block_pages=block_pages)
+    return np.asarray(out, np.float32)
+
+
+def _latent_ref(args):
+    q, pool, layer, tables, lens, new = args
+    return np.asarray(ref.paged_attention_ref(q, pool, layer, tables, lens, new, sm_scale=0.125,
+                                              v_width=V), np.float32)
+
+
+class TestPagedAttention:
+    """The latent (MQA-form) paged-decode kernel against the plain gather."""
+
+    @pytest.mark.parametrize("lens,layer,holes", [
+        ([1, 2], 0, ()),  # one and two tokens in the pool
+        ([8, 4, 16], 1, ()),  # each ends on a page boundary
+        ([12, 24, 36], 2, ()),  # each ends on a block boundary
+        ([30, 17], 1, ((0, 2), (1, 4))),  # a -1 entry below the length
+        ([45, 0, 3, 33], 2, ((3, 8),)),  # an idle lane beside ragged ones
+    ], ids=["one_token", "page_end", "block_end", "hole", "ragged_idle"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_sweep(self, lens, layer, holes, dtype):
+        args = _latent_case(dtype, lens, layer=layer, holes=holes)
+        out = _latent(args)
+        assert np.isfinite(out).all(), "a page past a length, or a -1 entry, was read"
+        np.testing.assert_allclose(out, _latent_ref(args), atol=_tol(dtype), rtol=_tol(dtype))
+
+    def test_idle_lane_reads_only_the_new_token(self):
+        """Length 0 reads no page: the lane attends to its new token alone."""
+        args = _latent_case(jnp.float32, [0, 5], layer=2)
+        out, new = _latent(args), np.asarray(args[-1])
+        np.testing.assert_allclose(out[0], np.broadcast_to(new[0, :V], out[0].shape), atol=1e-6)
+
+    def test_new_token_merge_equals_it_in_the_pool(self):
+        """The token not yet in the pool, folded into the softmax, gives what
+        the same token written into its page slot gives."""
+        q, pool, layer, tables, lens, new = args = _latent_case(jnp.float32, [13, 26], layer=1)
+        p = np.asarray(pool).copy()
+        for b, n in enumerate(np.asarray(lens)):
+            p[layer, tables[b, n // PAGE], n % PAGE] = np.asarray(new[b])
+        written = _latent_ref((q, jnp.asarray(p), layer, tables, lens + 1, None))
+        np.testing.assert_allclose(_latent(args), written, atol=2e-5, rtol=2e-5)
+
+    def test_block_size_invariance(self):
+        """One page a block, or 32 (the served size: a -1 entry in its last
+        page sets the top bit of the block's hole mask)."""
+        args = _latent_case(jnp.float32, [45, 7, 130], layer=1, holes=((2, 31),), n_p=40)
+        one, wide = _latent(args, block_pages=1), _latent(args, block_pages=32)
+        assert np.isfinite(wide).all()
+        np.testing.assert_allclose(one, wide, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(wide, _latent_ref(args), atol=2e-5, rtol=2e-5)
 
 
 class TestHotBins:

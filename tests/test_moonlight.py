@@ -208,3 +208,27 @@ def test_param_count_is_the_published_models():
     assert 2.8e9 < cfg.active_param_count() < 3.0e9  # "A3B"
     held = dataclasses.replace(cfg, experts_held=8).held_param_count()
     assert abs(held - 3.365e9) < 0.01e9  # one chip's share under EP8
+
+
+def test_decode_pages_read_counts_each_active_lanes_pooled_pages(shared):
+    """``decode_pages_read`` is, over decode steps, the pages the latent kernel
+    reads in a layer: each active lane's ``ceil(position / page)``. A request
+    of prompt S and n new tokens decodes at positions S .. S + n - 2 (its
+    first token comes from the prefill), whichever lane and step it gets."""
+    cfg, params = shared
+    page = 4
+    mgr = CentralManager(num_pages=40, fast_capacity=8, migration_budget=8, max_tenants=2,
+                         sample_period=1, exact_sampling=True)
+    kv = TieredPagedKV(cfg, 8, 32, page_tokens=page)
+    eng = ServingEngine(cfg, params, mgr, kv, max_batch=3, pages_per_seq=6, quest_pages=6,
+                        epoch_steps=1000, prompt_bucket=8)
+    eng.add_tenant("a", 0.1)
+    rng = np.random.default_rng(3)
+    specs = [(5, 7), (9, 3), (4, 10), (8, 5)]  # the fourth waits for a lane
+    for S, n in specs:
+        eng.submit("a", rng.integers(0, cfg.vocab_size, S).astype(np.int32), max_new_tokens=n)
+    while len(eng.finished) < len(specs):
+        eng.step()
+    want = sum(-(-pos // page) for S, n in specs for pos in range(S, S + n - 1))
+    assert eng.counters()["decode_pages_read"] == want
+    assert eng.decode_steps < sum(n - 1 for _, n in specs)  # lanes decoded side by side

@@ -9,6 +9,7 @@ this file loads the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,31 +63,59 @@ def test_page_move_compiles(one_chip, name, row, dtype, m):
     _compile(page_move, (pool, ids, ids))
 
 
-def test_mla_decode_step_updates_the_latent_pool_in_place(one_chip):
+def test_mla_decode_step_updates_the_latent_pool_in_place(one_chip, monkeypatch):
     """moonlight_ep8's decode step (32 lanes, 512-page tables, 8 experts
     held, a 12,288-page latent pool) compiles for the v5e with the pool
-    aliased and temporaries below 0.5 GB (one layer's gathered pages are
-    0.34 GB). A pool whose rows are not whole 128-lane tiles, or a scatter
-    into it by two index arrays, makes XLA copy all 6.8 GB of it."""
+    aliased, the paged kernel reading it in place, no [B, n_p * page, W]
+    gather of the table's pages, and temporaries below 0.1 GB (the gathered
+    pages alone were 0.34 GB). A pool whose rows are not whole 128-lane
+    tiles, or a scatter into it by two index arrays, makes XLA copy all
+    6.8 GB of it."""
+    from repro.kernels import ops
     from repro.kvcache.paged import lane_width
     from repro.models.model import get_model
     from repro.serving.paged_model import paged_decode_step
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)  # the host's backend is the CPU
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
                           jax.eval_shape(get_model(MOON).init, jax.random.PRNGKey(0)))
-    B, n_p = 32, 512
-    pool = spec((MOON.num_layers, MOON_SLOTS, 16, lane_width(MOON.latent_dim)), jnp.bfloat16)
+    B, n_p, W = 32, 512, lane_width(MOON.latent_dim)
+    pool = spec((MOON.num_layers, MOON_SLOTS, 16, W), jnp.bfloat16)
     table = spec((B, n_p), jnp.int32)
     lane = spec((B,), jnp.int32)
-    mem = paged_decode_step.lower(
+    compiled = paged_decode_step.lower(
         params, lane, lane, table, table, spec((B,), jnp.bool_), (pool,),
-        num_logical_pages=MOON_SLOTS, cfg=MOON, quest_pages=n_p).compile().memory_analysis()
-    pool_bytes = MOON.num_layers * MOON_SLOTS * 16 * 640 * 2
+        num_logical_pages=MOON_SLOTS, cfg=MOON, quest_pages=n_p).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text
+    assert f"bf16[{B},{n_p * 16},{W}]" not in text and f"bf16[{B},{n_p},16,{W}]" not in text
+    pool_bytes = MOON.num_layers * MOON_SLOTS * 16 * W * 2
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
+def test_latent_paged_attention_compiles_at_the_cells_shapes(one_chip):
+    """The kernel alone: 32 lanes of 16 absorbed heads over 512-page tables
+    into the [27, 12288, 16, 640] bf16 pool, which stays where it is."""
+    from repro.kernels.paged_attention import paged_attention
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, nh, W = 32, MOON.num_heads, 640
+    pool = spec((MOON.num_layers, MOON_SLOTS, 16, W), jnp.bfloat16)
+    compiled = paged_attention.lower(
+        spec((B, nh, W), jnp.bfloat16), pool, spec((), jnp.int32), spec((B, 512), jnp.int32),
+        spec((B,), jnp.int32), spec((B, W), jnp.bfloat16), sm_scale=0.072,
+        v_width=MOON.kv_lora_rank, interpret=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"= bf16\[{MOON.num_layers},{MOON_SLOTS},16,{W}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
 def test_page_copy_compiles_at_data_plane_width(one_chip):
@@ -103,8 +132,6 @@ def test_policy_tick_compiles_gather_free_at_paper_geometry(one_chip):
     v5e with no gather that outputs a page-length array (the owner-segment
     permutation's, about 7 ns an element on the chip) and no [T, P] buffer
     outside a fusion: its temporaries stay below one [16, P] bool."""
-    import re
-
     from repro.core import policy
     from repro.core.manager import CentralManager
 
