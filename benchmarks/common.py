@@ -1,31 +1,8 @@
 """Shared helpers for the paper-figure benchmarks."""
 from __future__ import annotations
 
-import os
-import platform as _platform
-import sys
 import time
 from typing import Dict, List
-
-import numpy as np
-
-
-def platform_metadata() -> Dict[str, object]:
-    """Host/device provenance stamped into every BENCH_*.json payload so
-    the perf gate can reason about cross-host comparisons (the committed
-    numbers rarely come from the machine re-measuring them)."""
-    import jax
-
-    return {
-        "python": sys.version.split()[0],
-        "jax": jax.__version__,
-        "numpy": np.__version__,
-        "platform": _platform.platform(),
-        "machine": _platform.machine(),
-        "cpu_count": os.cpu_count(),
-        "jax_backend": jax.default_backend(),
-        "jax_device_count": jax.local_device_count(),
-    }
 
 from repro.core.baselines import AutoNUMALike, HeMemStatic, TwoLM
 from repro.core.manager import CentralManager
@@ -40,7 +17,7 @@ TOTAL_PAGES = FAST_PAGES + SLOW_PAGES
 MIGRATION_BUDGET = 32  # ~6% of fast capacity per epoch (paper: 4 GB/s on 128 GB
 # DRAM ~ 3%). Budgets >~25% of fast capacity destabilize the control loop:
 # the one-epoch measurement lag + lambda=0.5 EWMA forms a period-2 limit
-# cycle with rotating starvation (see EXPERIMENTS.md §Paper-validation).
+# cycle with rotating starvation.
 
 
 def make_maxmem(fair_mode: bool = False, budget: int = MIGRATION_BUDGET,

@@ -10,10 +10,10 @@ Three deliverables:
   (~38% paper).
 * ``scenarios_bench()`` — the scripted arrive/depart scenario at 256k pages
   (the fused-engine scale) run by ALL FOUR policies, with per-phase
-  throughput/p99 curves; ``benchmarks/run.py`` writes it to
-  ``BENCH_scenarios.json``. The paper's qualitative ordering (MaxMem
+  throughput/p99 curves. The paper's qualitative ordering (MaxMem
   steady-state aggregate throughput >= every baseline) is asserted into the
-  payload.
+  payload, and ``main`` exits non-zero when it, the thrash completion or
+  the fault recovery contract fails.
 * ``vectorization_bench()`` — per-epoch wall time of the vectorized
   baselines against the frozen seed implementations at 64k pages
   (``seed_baselines_frozen.py``; interleaved min-of-reps because CI hosts
@@ -40,7 +40,6 @@ from benchmarks.common import (
     make_autonuma,
     make_hemem,
     make_maxmem,
-    platform_metadata,
 )
 from repro.core.baselines import AutoNUMALike, HeMemStatic, TwoLM
 from repro.core.manager import CentralManager
@@ -288,7 +287,7 @@ def _recovery_epochs(agg: list, fail: int, recover: int, frac: float = 0.9):
 
 
 def faults_bench(smoke: bool = False) -> dict:
-    """The ``faults`` section of BENCH_scenarios.json: all four policies on
+    """The ``faults`` section of :func:`scenarios_bench`: all four policies on
     the machine-failure + bandwidth-degrade schedule (MaxMem on the bounded
     queue data plane so the degrade hits a real drain rate), with the
     down-window zero-throughput contract and per-policy recovery epochs.
@@ -336,19 +335,17 @@ def faults_bench(smoke: bool = False) -> dict:
     }
 
 
-# --------------------------------------- fleet sweep mode (BENCH_fleet.json)
-# PR 4's committed single-device fleet sweep on the reference CI host
-# (BENCH_fleet.json @ 409f633: 16 machines x 64k pages x 96 epochs, fleet
-# wall 14.743 s = 104.19 aggregate machine-epochs/sec, vmap fleet + fully
-# serialized host driving). The fixed baseline the sharded/pipelined
-# executor is tracked against across PRs — same convention as
-# microbench.SEED_POLICY_EPOCH_64K_US.
+# ------------------------------------------------------- fleet sweep mode
+# The single-device fleet sweep of commit 409f633 on the reference CI host
+# (16 machines x 64k pages x 96 epochs, fleet wall 14.743 s = 104.19
+# aggregate machine-epochs/sec, vmap fleet + fully serialized host
+# driving). The fixed baseline the sharded/pipelined executor is compared
+# against in ``--sweep`` full mode.
 PR4_SWEEP_FLEET_AGG_EPS = 104.19
 PR4_SWEEP_COMMIT = "409f633 (single-device vmap fleet, serialized sweep driver)"
 # Enforced speedup floor vs the committed PR 4 baseline: set below the
 # 2-physical-core reference container's demonstrated 1.36-1.56x band (its
-# shared-tenancy speed swings that much run to run), so the gate catches
-# real regressions without flaking on container weather. The 1.8x
+# shared-tenancy speed swings that much run to run). The 1.8x
 # multi-core target is recorded and reported separately (DESIGN.md §6).
 SWEEP_SPEEDUP_FLOOR = 1.3
 def sweep_scenario(n_pages: int, n_epochs: int, max_tenants: int = 16) -> Scenario:
@@ -425,38 +422,8 @@ def _serial_point(cfg: dict, point: SweepPoint) -> float:
     return sim.run_scenario(sc).steady_state.agg_throughput
 
 
-def sweep_fleet_smoke() -> dict:
-    """Fleet-only smoke sweep for the CI perf gate: the gate checks that
-    every machine completes AND that the sharded/pipelined overlap metadata
-    is present (plus the tolerance-banded engine_smoke timings), so it must
-    not pay for the serial reference legs — the full comparison lives in
-    :func:`sweep_bench` / BENCH_fleet.json and the scenarios job's
-    ``--sweep --smoke`` leg."""
-    cfg = _sweep_config(smoke=True)
-    sc = sweep_scenario(cfg["n_pages"], cfg["n_epochs"], cfg["max_tenants"])
-    points = sweep_points(cfg["n_machines"], cfg["budget"])
-    res = run_sweep(
-        ScenarioSweep(scenario=sc, points=points),
-        num_pages=cfg["n_pages"], fast_capacity=cfg["fast"],
-        migration_budget=cfg["budget"], max_tenants=cfg["max_tenants"],
-        sample_period=100, policy_chunk=cfg["chunk"],
-    )
-    return {
-        "n_machines": cfg["n_machines"],
-        "wall_s": round(res.wall_s, 3),
-        "devices": res.devices,
-        "pipeline": res.pipeline,
-        "steady_state_agg_throughput": {
-            "fleet": {
-                k: round(r.steady_state.agg_throughput, 1)
-                for k, r in res.results.items()
-            },
-        },
-    }
-
-
 def sweep_bench(smoke: bool = False) -> dict:
-    """The BENCH_fleet.json sweep payload: the SAME ScenarioSweep executed
+    """The fleet sweep payload: the SAME ScenarioSweep executed
     four ways over identical workload timelines —
 
       * ``fleet`` — the sharded, double-buffered executor (DESIGN.md §6):
@@ -642,8 +609,8 @@ def sweep_bench(smoke: bool = False) -> dict:
         # 1.8x is the multi-core target (the sharded layouts need physical
         # cores to spread over); the floor is what the 2-physical-core
         # reference container demonstrates through its noise band — both
-        # recorded, the gate enforces the floor hard and reports the
-        # target row (DESIGN.md §6).
+        # recorded; full-mode ``--sweep`` fails below the floor and
+        # reports the target row (DESIGN.md §6).
         "meets_1_8x_vs_pr4": (
             None if smoke else bool(speedup_committed >= 1.8)
         ),
@@ -670,7 +637,7 @@ def sweep_bench(smoke: bool = False) -> dict:
 
 
 def scenarios_bench(smoke: bool = False) -> dict:
-    """The BENCH_scenarios.json payload: per-phase throughput/p99 for all
+    """The scenario payload: per-phase throughput/p99 for all
     four policies on the default scenario, plus the ordering check."""
     n_pages = 4096 if smoke else 262144
     n_epochs = 64 if smoke else 96
@@ -682,7 +649,6 @@ def scenarios_bench(smoke: bool = False) -> dict:
     tsc = thrash_scenario(n_pages, n_epochs)
     thrash = run_scenario_all(tsc, n_pages, bounded=True)
     payload = {
-        "platform": platform_metadata(),
         "scenario": {
             "name": sc.name, "n_pages": n_pages, "n_epochs": n_epochs,
             "events": [type(e).__name__ + "@" + str(e.epoch) for e in sc.events],

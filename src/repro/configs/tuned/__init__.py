@@ -6,9 +6,10 @@ one scenario family at one geometry, together with the measured
 tuned-vs-default metrics and the search provenance that produced it. The
 profiles are build artifacts in the same sense as the golden traces: they
 are regenerated by ``python -m repro.launch.hillclimb --scenario <family>
---commit-profile`` and their claims are re-validated by
-``BENCH_autotune.json`` + ``benchmarks/check_regression.py`` (a profile
-referenced by the bench that is missing here fails the gate loudly).
+--commit-profile``. ``tests/test_autotune.py`` re-validates their claims:
+``test_committed_smoke_profile_replays`` replays each ``*_4k`` profile
+against the defaults on its own family, and ``test_profiles_committed``
+fails loudly when a referenced profile is missing here.
 
 Schema (one file, ``<name>.json``)::
 

@@ -21,8 +21,9 @@ Two modes:
   ``PolicyParams.from_profile("thrash_4k")``. The paper-default candidate
   is always index 0 of generation 0, and the winner must weakly dominate
   it (aggregate throughput ≥ default AND LS p99 ≤ default), so the
-  committed tuned-vs-default claim in ``BENCH_autotune.json`` holds by
-  construction at the tuned geometry.
+  tuned-vs-default claim holds by construction at the tuned geometry
+  (``tests/test_autotune.py::test_committed_smoke_profile_replays``
+  replays it).
 * **online** (:class:`OnlineTuner`) — a controller attached to a live
   ``ColocationSim`` that watches phase telemetry (Arrive / SkewChange /
   ShiftWorkingSet events), re-dispatches a small tuning burst mid-run

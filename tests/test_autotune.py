@@ -10,8 +10,14 @@ online hot-swap mechanics (no recompile, no host-RNG perturbation), and
 the docs contract: ``docs/PARAMS.md`` documents every ``PolicyParams``
 field and the offline search space only tunes documented fields.
 
-Runs with only ``src`` on the path: the search tests use the built-in
-``skewshift`` family, never ``benchmarks/``.
+It also holds the autotuner's two behavioural claims: each committed
+smoke profile replayed against the paper defaults on its own family
+weakly dominates them, and the online re-tuner recovers a shifted tenant
+in fewer epochs than default params.
+
+The search tests use the built-in ``skewshift`` family. The
+``colocation`` and ``thrash`` replays build their scenarios from
+``benchmarks/dynamic_workload.py``, importable from the repo root.
 """
 from __future__ import annotations
 
@@ -39,7 +45,12 @@ from repro.launch.hillclimb import (
     OnlineTuner,
     PolicyAutotuner,
     TunerGeometry,
+    default_candidate,
+    family_scenario,
+    ls_tenants,
+    measure_history,
     recovery_epochs,
+    resolve_knobs,
     skewshift_scenario,
 )
 
@@ -117,8 +128,8 @@ def test_resume_state_mismatch_rejected(tmp_path):
 
 # -------------------------------------------------------- committed profiles
 def test_profiles_committed():
-    # the bench references these names; deleting one must be loud (the perf
-    # gate checks the same invariant against BENCH_autotune.json)
+    # the replays below and the storm bench reference these names;
+    # deleting one must be loud
     assert {"colocation_4k", "thrash_4k", "skewshift_4k",
             "storm_64k"} <= set(profile_names())
 
@@ -164,6 +175,77 @@ def test_profile_override():
     name = profile_names()[0]
     p = params_from_profile(name, sample_period=77)
     assert int(p.sample_period) == 77
+
+
+def _geometry_from_profile(prof):
+    g = prof["geometry"]
+    return TunerGeometry(
+        n_pages=int(g["n_pages"]),
+        n_epochs=int(g["n_epochs"]),
+        fast=int(g["fast_capacity"]),
+        queue_size=int(g["queue_size"]),
+        max_tenants=int(g["max_tenants"]),
+        policy_chunk=int(g["policy_chunk"]),
+    )
+
+
+def _tuned_point(prof, name, seed):
+    p = prof["params"]
+    return SweepPoint(
+        name,
+        seed=seed,
+        migration_budget=int(p["migration_budget"]),
+        sample_period=int(p["sample_period"]),
+        ewma_lambda=float(p["ewma_lambda"]),
+        hysteresis=float(p["hysteresis"]),
+        num_bins=int(p["num_bins"]),
+        alloc_headroom=int(p["alloc_headroom"]),
+    )
+
+
+def tuned_vs_default(profile):
+    """Replay one committed profile against the paper defaults: one
+    two-point fleet sweep at the profile's own geometry, same seed, scored
+    over the profile's window. Returns ``(default, tuned)``, each an
+    ``(agg_throughput, ls_p99_s)`` pair."""
+    prof = load_profile(profile)
+    geom = _geometry_from_profile(prof)
+    scenario = family_scenario(prof["family"], geom)
+    seed = int(prof["search"].get("eval_seed", 0))
+    default_kw = resolve_knobs(default_candidate(), geom)
+    points = (
+        SweepPoint("default", seed=seed, **default_kw),
+        _tuned_point(prof, "tuned", seed),
+    )
+    res = run_sweep(
+        ScenarioSweep(scenario=scenario, points=points),
+        num_pages=geom.n_pages,
+        fast_capacity=geom.fast,
+        migration_budget=default_kw["migration_budget"],
+        max_tenants=geom.max_tenants,
+        queue_size=geom.queue_size,
+        policy_chunk=geom.policy_chunk,
+    )
+    window = tuple(prof["search"]["scored_window"])
+    ls = ls_tenants(scenario)
+    return tuple(
+        measure_history(res.results[k].history, window, ls)
+        for k in ("default", "tuned")
+    )
+
+
+@pytest.mark.parametrize("family", ["colocation", "thrash", "skewshift"])
+def test_committed_smoke_profile_replays(family):
+    """The committed ``<family>_4k`` profile, replayed on its own family
+    through the fleet sweep, weakly dominates the paper defaults: tuned
+    aggregate throughput >= default AND tuned LS p99 <= default. The
+    replay is deterministic, so equality passes."""
+    profile = f"{family}_4k"
+    assert load_profile(profile)["family"] == family
+    (d_agg, d_p99), (t_agg, t_p99) = tuned_vs_default(profile)
+    assert d_agg > 0 and d_p99 > 0
+    assert t_agg >= d_agg, (profile, t_agg, d_agg)
+    assert t_p99 <= d_p99, (profile, t_p99, d_p99)
 
 
 # ------------------------------------------------------- SweepPoint plumbing
@@ -255,6 +337,46 @@ def test_online_swap_is_in_plan_budget():
         assert 1 <= r["budget"] <= plan  # runtime budget capped by the buffer
     # hot-swap left a working manager behind
     sim.run_epoch()
+
+
+def online_recovery(n_pages=2048, n_epochs=48, seed=0):
+    """Default params against an :class:`OnlineTuner` watching SkewChange
+    events on the skewshift probe. Returns the epochs each leg takes for
+    the shifted ``kvs`` tenant to regain 95% of its pre-shift throughput,
+    ``(default, online)``. Both legs share machine shapes (the plan buffer
+    is sized fast/2, so the tuner can raise the budget without a retrace)
+    and start from the same default traced params."""
+    fast = n_pages // 8
+    scenario = skewshift_scenario(n_pages, n_epochs)
+    shift = n_epochs // 2
+
+    def make_sim():
+        mgr = CentralManager(num_pages=n_pages, fast_capacity=fast,
+                             migration_budget=fast // 2, max_tenants=8)
+        mgr.params = mgr.params._replace(
+            migration_budget=jnp.int32(max(fast // 8, 8)))
+        return ColocationSim(mgr, OPTANE, seed=seed, policy_chunk=2)
+
+    res_d = make_sim().run_scenario(scenario)
+    sim_o = make_sim()
+    tuner = OnlineTuner(sim_o, seed=seed, triggers=(SkewChange,))
+    res_o = sim_o.run_scenario(scenario, on_event=tuner.on_event)
+    assert tuner.retunes, "the SkewChange must trigger a re-tune"
+    rec_d, base_d = recovery_epochs(res_d.history, shift, tenant="kvs")
+    rec_o, base_o = recovery_epochs(res_o.history, shift, tenant="kvs")
+    # the burst draws from its own RNG stream: identical before the shift
+    assert base_o == pytest.approx(base_d, rel=1e-6)
+    return rec_d, rec_o
+
+
+def test_online_retune_recovers_faster():
+    """After a SkewChange the online re-tuner brings the shifted LS tenant
+    back to 95% of its pre-shift throughput in fewer epochs than default
+    params. The observable is that tenant's own throughput: the aggregate
+    masks the dip, since a starved LS tenant frees bandwidth for the batch
+    tenants."""
+    rec_d, rec_o = online_recovery()
+    assert rec_o < rec_d, (rec_o, rec_d)
 
 
 # --------------------------------------------------------------------- docs

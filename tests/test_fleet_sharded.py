@@ -293,6 +293,8 @@ class TestPipelinedSweep:
         plain = run_sweep(ScenarioSweep(scenario=sc, points=points),
                           devices=1, pipeline=False, trim_stats=False, **kw)
         assert piped.pipeline and not plain.pipeline
+        assert piped.devices >= 1 and plain.devices == 1
+        assert set(piped.results) == set(plain.results) == {p.name for p in points}
         for p in points:
             mgr_kw = dict(
                 num_pages=1024, fast_capacity=256,

@@ -14,9 +14,9 @@ Covers the three tentpole mechanisms of the scaling PR:
     sort at T >= 256 under heavy register/allocate/free/unregister churn,
     with the permutation invariants checked after EVERY mutation.
 
-Plus the scaling-bench scaffolding: the geometry-parameterized
-``scale_colocation`` scenario, the log-log slope fit, and the fleet
-``live_bytes`` accounting.
+Plus the geometry-parameterized ``scale_colocation`` scenario (its shape
+and a manager-grade churn run through it) and the fleet ``live_bytes``
+accounting.
 """
 import jax
 import jax.numpy as jnp
@@ -27,9 +27,12 @@ from repro.core import tiling
 from repro.core.manager import CentralManager
 from repro.core.types import (
     MAX_TENANT_SLOTS,
+    TIER_FAST,
+    TIER_SLOW,
     MigrationQueue,
     OwnerSegments,
     PageState,
+    PolicyParams,
     PolicyState,
     segments_build_host,
     segments_update_host,
@@ -86,16 +89,39 @@ def test_tiled_cumsum_float_falls_back_to_plain_scan():
     )
 
 
+def make_scale_state(P: int, T: int, seed: int = 0) -> PolicyState:
+    """A production-shaped solo policy state at geometry (P, T): every
+    page owned, ~25% fast-resident, owner segments attached (every
+    manager-grade state carries them) and a Poisson pending backlog."""
+    rng = np.random.default_rng(seed)
+    st = PolicyState.create(P, T)
+    pages = st.pages._replace(
+        owner=jnp.asarray(rng.integers(0, T, P), st.pages.owner.dtype),
+        tier=jnp.asarray(
+            np.where(rng.random(P) < 0.25, TIER_FAST, TIER_SLOW), jnp.int8),
+    )
+    tenants = st.tenants._replace(
+        active=jnp.ones((T,), bool),
+        t_miss=jnp.asarray(rng.uniform(0.05, 1.0, T), jnp.float32),
+        arrival=jnp.arange(T, dtype=jnp.int32),
+    )
+    segs = OwnerSegments.build(np.asarray(pages.owner), T)
+    pending = jnp.asarray(rng.poisson(200, P), jnp.uint32)
+    return st._replace(pages=pages, tenants=tenants, pending=pending, segs=segs)
+
+
 def test_full_epoch_identical_across_tiling_threshold():
     """The whole fused tick is bit-identical whichever trace the heuristic
     selects: run one epoch at a tiled size, then force the plain-scan trace
     by raising the threshold, and compare every output leaf."""
-    from benchmarks.scale_bench import make_scale_state, _scale_params
     from repro.core import policy
 
     P, T, R = tiling.CUMSUM_TILE_THRESHOLD + 8192, 64, 512
     st = make_scale_state(P, T, seed=7)
-    params = _scale_params(P, R)
+    params = PolicyParams(
+        fast_capacity=jnp.int32(P // 4), migration_budget=jnp.int32(R),
+        sample_period=jnp.int32(100),
+    )
 
     def one_epoch():
         policy._jitted_epoch_step.cache_clear()  # drop the cached jit trace
@@ -252,7 +278,7 @@ def test_manager_incremental_segs_through_churn_t256():
         check()
 
 
-# --------------------------------------------------- scale bench scaffolding
+# ------------------------------------------------ scale colocation scenario
 def test_scale_colocation_geometry():
     from repro.core.scenario import Arrive, Depart, scale_colocation
 
@@ -271,13 +297,28 @@ def test_scale_colocation_geometry():
         scale_colocation(64, 16, 16)  # geometry too thin
 
 
-def test_fit_slope():
-    from benchmarks.scale_bench import fit_slope
+def test_scale_colocation_churn_run_completes():
+    """A manager-grade run of the scenario: the churn cohort registers,
+    allocates, runs and departs in batch waves, and the run completes all
+    three phases with only the core tenants left registered."""
+    from repro.core.scenario import Depart, scale_colocation
+    from repro.core.simulator import OPTANE, ColocationSim
 
-    sizes = [65536, 262144, 1048576]
-    assert fit_slope(sizes, [s / 1000 for s in sizes]) == pytest.approx(1.0)
-    assert fit_slope(sizes, [7.0, 7.0, 7.0]) == pytest.approx(0.0)
-    assert fit_slope(sizes, [s ** 1.5 for s in sizes]) == pytest.approx(1.5)
+    P, T, n_epochs = 4096, 8, 8
+    sc = scale_colocation(P, T, n_epochs)
+    churn = {e.name for e in sc.events if isinstance(e, Depart)}
+    depart_at = min(e.epoch for e in sc.events if isinstance(e, Depart))
+    mgr = CentralManager(
+        num_pages=P, fast_capacity=P // 4, migration_budget=P // 32,
+        max_tenants=T, sample_period=100, seed=0,
+    )
+    res = ColocationSim(mgr, OPTANE, seed=1, policy_chunk=4).run_scenario(sc)
+    assert len(res.phases) == 3
+    assert len(res.history) == n_epochs
+    assert res.steady_state.agg_throughput > 0
+    assert churn & set(res.history[depart_at - 1].throughput)
+    assert not churn & set(res.history[-1].throughput)
+    assert int(np.asarray(mgr._state.tenants.active).sum()) == T - len(churn)
 
 
 def test_fleet_live_bytes_scales_with_machines():
